@@ -550,6 +550,8 @@ _OUT_DEFAULT = {"offload": "BENCH_offload.json",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     argv = sys.argv[1:]
     out_path = None
     if "--out" in argv:

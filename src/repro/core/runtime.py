@@ -283,6 +283,11 @@ class OffloadEngine:
 
     # ------------------------------------------------------------------ jit
     def _build_jitted(self):
+        """Jit the verify-path steps.  Every weight a step reads is an
+        argument (``rp`` = the resident params below): a closed-over array
+        would be baked into the executable as a constant — at published
+        widths that is one private copy of the embedding, head and
+        attention weights per compiled step."""
         cfg = self.cfg
         mp = self.tparams["layers"]
 
@@ -318,14 +323,14 @@ class OffloadEngine:
             y = ffn_forward(lp["ffn"], h2, cfg.ffn_activation)
             return x + y, cache_l
 
-        def embed(tokens):
-            return jnp.take(self.tparams["wte"], tokens, axis=0)
+        def embed(rp, tokens):
+            return jnp.take(rp["wte"], tokens, axis=0)
 
-        def head(x):
-            xf = L.rms_norm(x, self.tparams["ln_f"], cfg.norm_eps)
+        def head(rp, x):
+            xf = L.rms_norm(x, rp["ln_f"], cfg.norm_eps)
             if cfg.tie_embeddings:
-                return jnp.einsum("bsd,vd->bsv", xf, self.tparams["wte"])
-            return jnp.einsum("bsd,dv->bsv", xf, self.tparams["head"])
+                return jnp.einsum("bsd,vd->bsv", xf, rp["wte"])
+            return jnp.einsum("bsd,dv->bsv", xf, rp["head"])
 
         # per-MoE-layer params *without* the resident expert weights: the hot
         # path must only ever read experts from the cache slot buffers.
@@ -334,27 +339,30 @@ class OffloadEngine:
                                    "gate": mp["moe"]["gate"]}
         if cfg.num_shared_experts:
             lp_scan["shared"] = mp["moe"]["shared"]
+        self._rp = {k: self.tparams[k]
+                    for k in ("wte", "ln_f", "head", "dense_layers")
+                    if k in self.tparams}
+        self._rp["layers"] = lp_scan
 
-        def dense_stack(x, dcache, pos):
+        def dense_stack(dense_lp, x, dcache, pos):
             def dbody(carry, xs):
                 lp, cl = xs
                 xo, ncl = dense_block(lp, carry, cl, pos)
                 return xo, ncl
-            return jax.lax.scan(dbody, x,
-                                (self.tparams["dense_layers"], dcache))
+            return jax.lax.scan(dbody, x, (dense_lp, dcache))
 
-        def verify_fast(bufs, table, history, tokens, pos, tcache):
+        def verify_fast(rp, bufs, table, history, tokens, pos, tcache):
             """Whole verify block as ONE device computation (lax.scan over
             the stacked MoE layers), speculating that every routed expert is
             cache-resident.  Returns (logits, all_hit, new_tcache,
             new_history, n_active); nothing here syncs to host."""
             self._fast_traces += 1        # trace-time side effect only
-            x = embed(tokens)
+            x = embed(rp, tokens)
             T = tokens.shape[1]
             new_tcache = dict(tcache)
-            if "dense_layers" in self.tparams:
+            if "dense_layers" in rp:
                 x, new_tcache["dense_layers"] = dense_stack(
-                    x, tcache["dense_layers"], pos)
+                    rp["dense_layers"], x, tcache["dense_layers"], pos)
 
             def mbody(carry, xs):
                 x, ok, nact = carry
@@ -376,12 +384,12 @@ class OffloadEngine:
 
             (x, ok, nact), (nlayers, act) = jax.lax.scan(
                 mbody, (x, jnp.bool_(True), jnp.float32(0.0)),
-                (lp_scan, tcache["layers"], table))
+                (rp["layers"], tcache["layers"], table))
             new_tcache["layers"] = nlayers
             new_history = history + act.astype(history.dtype)
-            return head(x), ok, new_tcache, new_history, nact
+            return head(rp, x), ok, new_tcache, new_history, nact
 
-        def verify_fast_batched(bufs, table, hists, tokens, pos, tcaches):
+        def verify_fast_batched(rp, bufs, table, hists, tokens, pos, tcaches):
             """Whole scheduling ROUND as one device computation: every ready
             session's verify block in a single fused dispatch.  ``tokens`` is
             a tuple of [1, T_i] blocks (T_i may be ragged), ``hists`` /
@@ -411,10 +419,11 @@ class OffloadEngine:
             new_tcaches = [dict(tc) for tc in tcaches]
             xs = []
             for i in range(n):
-                x = embed(tokens[i])
-                if "dense_layers" in self.tparams:
+                x = embed(rp, tokens[i])
+                if "dense_layers" in rp:
                     x, new_tcaches[i]["dense_layers"] = dense_stack(
-                        x, tcaches[i]["dense_layers"], pos[i])
+                        rp["dense_layers"], x, tcaches[i]["dense_layers"],
+                        pos[i])
                 xs.append(x)
 
             def mbody(carry, scan_xs):
@@ -456,13 +465,13 @@ class OffloadEngine:
                       tuple(jnp.float32(0.0) for _ in range(n)))
             (xs_f, ok_f, nact_f), (nlayers, acts) = jax.lax.scan(
                 mbody, carry0,
-                (lp_scan, tuple(tc["layers"] for tc in tcaches), table))
+                (rp["layers"], tuple(tc["layers"] for tc in tcaches), table))
             for i in range(n):
                 new_tcaches[i]["layers"] = nlayers[i]
             new_hists = tuple(hists[i] + acts[i].astype(hists[i].dtype)
                               for i in range(n))
             xcat = jnp.concatenate(xs_f, axis=1)          # [1, ΣT, d]
-            return (head(xcat), jnp.stack(ok_f), tuple(new_tcaches),
+            return (head(rp, xcat), jnp.stack(ok_f), tuple(new_tcaches),
                     new_hists, jnp.stack(nact_f))
 
         self._attn_half = jax.jit(attn_half)
@@ -505,7 +514,7 @@ class OffloadEngine:
         hist = jnp.zeros(self._hist_shape, jnp.float32)
         for n in lens:
             tokens = jnp.zeros((1, n + 1), jnp.int32)
-            self._verify_fast(bufs, table, hist, tokens, 0, tcache)
+            self._verify_fast(self._rp, bufs, table, hist, tokens, 0, tcache)
 
     def _layer_params(self, l: int):
         """Per-layer param slice for the slow path — attention + norms +
@@ -598,7 +607,8 @@ class OffloadEngine:
             with self.cache.lock:
                 bufs, table = self.cache.snapshot()
                 logits, ok, ncache, nhist, nact = self._verify_fast(
-                    bufs, table, st.history_dev, tokens, pos, tcache)
+                    self._rp, bufs, table, st.history_dev, tokens, pos,
+                    tcache)
             if bool(self._readback(ok)):          # sync 1 of ≤2 per block
                 st.history_dev = nhist
                 st.fast_active_dev = st.fast_active_dev + nact
@@ -617,12 +627,12 @@ class OffloadEngine:
         the whole block resolved from cache."""
         st = self._st
         cfg = self.cfg
-        x = self._embed(tokens)
+        x = self._embed(self._rp, tokens)
         T = tokens.shape[1]
         total_misses = 0
-        if "dense_layers" in self.tparams:
+        if "dense_layers" in self._rp:
             x, tcache["dense_layers"] = self._dense_stack(
-                x, tcache["dense_layers"], pos)
+                self._rp["dense_layers"], x, tcache["dense_layers"], pos)
         new_layers = []
         for l in range(self.store.num_layers):
             lp = self._layer_params(l)
@@ -682,7 +692,7 @@ class OffloadEngine:
             else:
                 st.fast_ok = False
             self._fast_hint = st.fast_ok   # seed arming of future sessions
-        return self._head(x), tcache
+        return self._head(self._rp, x), tcache
 
     # ------------------------------------------------------------ session API
     # The turn-based serving surface: a scheduler (core/engine.py's
@@ -948,7 +958,7 @@ class OffloadEngine:
             bufs, table = self.cache.snapshot()
             logits, ok_vec, new_tcaches, new_hists, nact_vec = \
                 self._verify_fast_batched(
-                    bufs, table,
+                    self._rp, bufs, table,
                     tuple(st.history_dev for st in sts),
                     tuple(blocks),
                     tuple(st.pos for st in sts),

@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.kernels.moe_gemm import _down_kernel, _gate_up_kernel, moe_gemm
 
 
@@ -150,7 +148,7 @@ def _gelu_grouped(xg: jax.Array, wu: jax.Array, wd: jax.Array,
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, i, j, kk: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((S, C, f), xg.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xg, wu)
@@ -164,7 +162,7 @@ def _gelu_grouped(xg: jax.Array, wu: jax.Array, wd: jax.Array,
         out_specs=pl.BlockSpec((1, bc, bd), lambda e, i, j, kk: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((S, C, d), xg.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(h, wd)

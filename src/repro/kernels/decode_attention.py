@@ -18,7 +18,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -88,7 +87,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens, qf, kf, vf)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _gate_up_kernel(x_ref, wg_ref, wu_ref, h_ref, accg_ref, accu_ref):
     kk = pl.program_id(3)
@@ -83,7 +81,7 @@ def moe_gemm(xg: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
         out_shape=jax.ShapeDtypeStruct((E, C, f), xg.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32),
                         pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xg, wg, wu)
@@ -98,7 +96,7 @@ def moe_gemm(xg: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
         out_specs=pl.BlockSpec((1, bc, bd), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C, d), xg.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(h, wd)

@@ -6,17 +6,10 @@ from __future__ import annotations
 from typing import Dict, Optional
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: make_mesh has no axis_types kwarg (all Auto)
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

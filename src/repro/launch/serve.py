@@ -29,8 +29,18 @@ fault); the per-request report grows the resilience counters
 prints the engine's final health.  ``--deadline-s`` arms a per-request
 wall-clock budget (``finish_reason="deadline"`` when it expires).
 
+Model size: by default the target and its draft are the CPU-sized
+``reduced`` pair (d_model 64, float32).  ``--layers N`` instead serves the
+published widths in the config's own dtype with both models cut to N
+layers — the size to run on an accelerator.  The first lines printed list
+every cut.
+
     PYTHONPATH=src python -m repro.launch.serve --arch mixtral-8x7b \
         --decode sd --offload spmoe --tokens 32 --requests 2
+
+    # published Mixtral widths, one layer, on the chip
+    PYTHONPATH=src python -m repro.launch.serve --layers 1 --cache-slots 7 \
+        --prompt-len 16 --tokens 16 --requests 4
 
     # four requests, two decoded concurrently per turn
     PYTHONPATH=src python -m repro.launch.serve --requests 4 --concurrency 2
@@ -43,13 +53,17 @@ wall-clock budget (``finish_reason="deadline"`` when it expires).
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import List, Optional, Tuple
 
 import jax
 
+from repro.configs.base import ModelConfig
 from repro.core.chaos import ChaosConfig
 from repro.configs.registry import get_config, get_draft_config
 from repro.core.engine import (DECODE_POLICIES, OFFLOAD_POLICIES, Engine,
                                EngineConfig, Request, derive_draft_config)
+from repro.launch.compile_cache import enable_compile_cache
 
 # legacy --policy values -> (decode, offload)
 LEGACY_POLICY = {
@@ -63,20 +77,44 @@ LEGACY_POLICY = {
 }
 
 
-def reduced_pair(arch: str):
-    cfg = get_config(arch).reduced(dtype="float32")
+def model_pair(arch: str, layers: Optional[int] = None
+               ) -> Tuple[ModelConfig, ModelConfig, List[str]]:
+    """(target, draft, cuts) for serving ``arch``.
+
+    ``layers=None`` gives the CPU-sized pair: both configs ``reduced`` to
+    d_model 64 in float32.  Otherwise both keep their published widths and
+    dtype and are cut to ``layers`` layers alike.  The draft is the
+    registry's pairing for ``arch`` (Mixtral's is Mistral-7B), or the
+    engine's derived dense sibling where the registry has none.  ``cuts``
+    names every way the pair differs from the published configs."""
+    target = get_config(arch)
     draft = get_draft_config(arch)
-    if draft is not None and draft.name != cfg.name:
-        dcfg = draft.reduced(dtype="float32")
-    else:
-        dcfg = derive_draft_config(cfg)
-    return cfg, dcfg
+    if layers is None:
+        cfg = target.reduced(dtype="float32")
+        dcfg = draft.reduced(dtype="float32") if draft is not None \
+            else derive_draft_config(cfg)
+        return cfg, dcfg, [f"reduced widths (d_model {cfg.d_model}, "
+                           f"{cfg.num_layers} layers, vocab "
+                           f"{cfg.vocab_size})", "dtype float32"]
+    if not target.first_dense_layers < layers <= target.num_layers:
+        raise ValueError(f"--layers must be in ({target.first_dense_layers}, "
+                         f"{target.num_layers}] for {arch}")
+    cfg = dataclasses.replace(target, num_layers=layers)
+    dcfg = dataclasses.replace(draft, num_layers=layers) \
+        if draft is not None else derive_draft_config(cfg)
+    dcut = (f"draft {draft.name}: num_layers {draft.num_layers} -> {layers}"
+            if draft is not None else
+            f"draft {dcfg.name}: derived from the cut target")
+    return cfg, dcfg, [f"target {target.name}: num_layers "
+                       f"{target.num_layers} -> {layers}", dcut]
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve the published widths cut to this many "
+                         "layers (default: the CPU-sized reduced pair)")
     ap.add_argument("--decode", default=None, choices=DECODE_POLICIES,
                     help="token-commit policy (default: sd)")
     ap.add_argument("--offload", default=None, choices=OFFLOAD_POLICIES,
@@ -132,7 +170,10 @@ def main():
         decode, offload = LEGACY_POLICY[args.policy]
         print(f"# --policy {args.policy} is deprecated; use "
               f"--decode {decode} --offload {offload}")
-    cfg, dcfg = reduced_pair(args.arch)
+    cfg, dcfg, cuts = model_pair(args.arch, args.layers)
+    for cut in cuts:
+        print(f"# cut: {cut}")
+    enable_compile_cache()
     if decode is None:
         decode = "sd"
     if offload is None:

@@ -27,11 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.configs.base import ModelConfig
 from repro.kernels.ref import ssd_ref
 from repro.models import layers as L
@@ -48,8 +43,7 @@ def _mamba_block_local(p: Params, x: jax.Array, cfg: ModelConfig,
     """
     Bsz, S, _ = x.shape
     d_in, H, N, conv_ch = M._dims(cfg)
-    n_shards = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-                else jax.lax.psum(1, axis))      # jax 0.4.x spelling
+    n_shards = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
 
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -125,20 +119,12 @@ def seq_parallel_forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp_size = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
     bspec = dp if (dp and tokens.shape[0] % dp_size == 0) else None
-    try:
-        fn = _shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), P(bspec, axis)),
-            out_specs=P(bspec, axis, None),
-            check_vma=False,
-        )
-    except TypeError:  # jax 0.4.x spells the kwarg check_rep
-        fn = _shard_map(
-            body, mesh=mesh,
-            in_specs=(P(), P(bspec, axis)),
-            out_specs=P(bspec, axis, None),
-            check_rep=False,
-        )
+    fn = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), P(bspec, axis)),
+        out_specs=P(bspec, axis, None),
+        check_vma=False,
+    )
     x = fn(params, tokens)
     logits = jnp.einsum("bd,dv->bv", x[:, -1, :], params["head"]) \
         if not cfg.tie_embeddings else \

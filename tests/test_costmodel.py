@@ -61,10 +61,7 @@ def test_flops_cross_validated_with_xla():
     tokens = jax.ShapeDtypeStruct((B, S), jnp.int32)
     compiled = jax.jit(lambda p, t: model.forward(p, t)[0]).lower(
         params, tokens).compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):              # jax 0.4.x: one entry per device
-        ca = ca[0]
-    xla_flops = ca["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     shape = ShapeConfig("t", S, B, "prefill")
     ours = step_flops(cfg, shape)["total"]
     # XLA counts a superset (softmax, norms, rope); ours counts matmuls.

@@ -4,12 +4,7 @@ actually be distributed."""
 import jax
 import numpy as np
 import pytest
-from jax.sharding import AbstractMesh, PartitionSpec as P
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: AbstractMesh takes ((name, size), ...)
-    AxisType = None
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.configs.base import SHAPES
 from repro.configs.registry import ASSIGNED, get_config
@@ -20,8 +15,6 @@ from repro.models.registry import build_model
 def _mesh(multi_pod=False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return AbstractMesh(tuple(zip(axes, shape)))
     return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

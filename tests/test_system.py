@@ -1,6 +1,7 @@
 """End-to-end system tests: training convergence, checkpoint/restart
 equivalence, fault-tolerant supervision with elastic re-planning, and the
 full SP-MoE serving path."""
+import dataclasses
 import tempfile
 
 import jax
@@ -10,7 +11,7 @@ import pytest
 
 from conftest import make_draft_for
 from repro.configs.base import RunConfig, ShapeConfig
-from repro.configs.registry import get_config
+from repro.configs.registry import get_config, get_draft_config
 from repro.core.engine import Engine, EngineConfig, Request
 from repro.core.sd import greedy_generate
 from repro.launch.train import Trainer
@@ -94,3 +95,41 @@ def test_spmoe_serving_end_to_end():
     assert stats["prefetched"] > 0
     assert 0 <= stats["hit_rate"] <= 1
     assert stats["cutoff_layer"] >= 0
+
+
+@pytest.mark.parametrize("layers", [None, 2], ids=["reduced", "depth-2"])
+def test_serving_model_pair(layers):
+    """The serving pair: CPU-reduced float32 by default; with a depth cut,
+    target and draft keep their published widths and dtype, are cut to the
+    same depth, and every cut is listed."""
+    from repro.launch.serve import model_pair
+    cfg, dcfg, cuts = model_pair("mixtral-8x7b", layers)
+    target, draft = get_config("mixtral-8x7b"), get_draft_config("mixtral-8x7b")
+    if layers is None:
+        assert cfg == target.reduced(dtype="float32")
+        assert dcfg == draft.reduced(dtype="float32")
+    else:
+        assert cfg == dataclasses.replace(target, num_layers=layers)
+        assert dcfg == dataclasses.replace(draft, num_layers=layers)
+        assert all(f"-> {layers}" in c for c in cuts)
+    assert len(cuts) == 2
+    with pytest.raises(ValueError):
+        model_pair("mixtral-8x7b", target.num_layers + 1)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The environment's cache directory wins and is left to JAX; otherwise
+    the cache goes to the checkout's fixed ``.jax_cache``."""
+    from repro.launch import compile_cache as CC
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert CC.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = CC.enable_compile_cache()
+        assert path == str(CC.CHECKOUT / ".jax_cache")
+        assert (CC.CHECKOUT / "chip_smoke.py").is_file()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
