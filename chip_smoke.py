@@ -91,7 +91,7 @@ def run(eng, reqs, concurrency, label, failures):
     t0 = time.perf_counter()
     results = eng.serve_all(reqs, concurrency=concurrency)
     wall = time.perf_counter() - t0
-    print(f"[{label}] concurrency={concurrency} wall_s={wall:.3f}")
+    print(f"[{label}] concurrency={concurrency} serve_s={wall:.3f}")
     for r in results:
         m = r.metrics
         print(f"[{label} {r.request_id}] finish={r.finish_reason} "
@@ -102,7 +102,8 @@ def run(eng, reqs, concurrency, label, failures):
               f"on_demand_loads={m.on_demand_loads} "
               f"hit_rate={m.hit_rate:.3f} "
               f"acceptance_rate={m.acceptance_rate:.3f} "
-              f"host_syncs={m.host_syncs} wall_s={m.wall_s:.3f}")
+              f"host_syncs={m.host_syncs} verify_syncs={m.verify_syncs} "
+              f"h2d_bytes={m.h2d_bytes}")
         if r.finish_reason != "length":
             failures.append(f"{label} {r.request_id}: finish_reason "
                             f"{r.finish_reason!r}")

@@ -49,7 +49,7 @@ def main():
             for p in prompts]
 
     print(f"{'policy':14s} {'lossless':9s} {'hit_rate':9s} {'prefetched':11s} "
-          f"{'on_demand':10s} {'evict':6s} {'wall_s':7s}")
+          f"{'on_demand':10s} {'evict':6s}")
     for policy in OFFLOAD_POLICIES:
         config = EngineConfig(model=cfg, draft=dcfg, decode="sd",
                               offload=policy, cache_slots=args.cache_slots,
@@ -65,7 +65,7 @@ def main():
             m = eng.metrics()    # cumulative across the request stream
         print(f"{policy:14s} {str(ok):9s} {m.hit_rate:9.2%} "
               f"{m.prefetched:<11d} {m.on_demand_loads:<10d} "
-              f"{m.evictions:<6d} {m.wall_s:7.1f}")
+              f"{m.evictions:<6d}")
 
 
 if __name__ == "__main__":

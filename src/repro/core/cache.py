@@ -70,6 +70,14 @@ class ExpertCache:
         self.free: List[int] = list(range(num_slots))
         self.lock = threading.RLock()
         self._insert = jax.jit(_batched_insert, donate_argnums=(0,))
+        # On the CPU backend jax.device_put of an aligned numpy array
+        # aliases it, and the scatter ``insert`` dispatches may run after the
+        # caller refills that memory (HostExpertStore.fetch reuses a staging
+        # buffer two fetches later), so ``insert`` copies the arrays there
+        # first.  Other backends copy them during the device_put call.
+        self._copy_staged = all(
+            d.platform == "cpu"
+            for d in next(iter(self.bufs.values())).devices())
         # device-resident page-table mirror [L, E] -> slot | -1
         self.table_shape = table_shape
         self.table_dev: Optional[jax.Array] = (
@@ -91,6 +99,7 @@ class ExpertCache:
         self.misses = 0
         self.evictions = 0
         self.prefetch_evicted = 0   # evicted before ever being used
+        self.h2d_bytes = 0          # bytes handed to jax.device_put
 
     # ------------------------------------------------------------------ reads
     def contains(self, key: ExpertKey) -> bool:
@@ -172,9 +181,11 @@ class ExpertCache:
         ``wait()`` for a hard barrier.
 
         ``stats`` (optional) is credited with this call's ``evictions`` /
-        ``prefetch_evicted_unused`` — how per-session I/O ledgers attribute
-        eviction work to the session (or prefetch task) that caused it
-        instead of to whoever's turn the async load happened to land in.
+        ``prefetch_evicted_unused`` / ``h2d_bytes`` — how per-session I/O
+        ledgers attribute eviction and transfer work to the session (or
+        prefetch task) that caused it instead of to whoever's turn the
+        async load happened to land in.  Keys already resident transfer
+        nothing.
         """
         if not keys:
             return []
@@ -213,8 +224,14 @@ class ExpertCache:
                     picked = {n: arr for n, arr in host_arrays.items()}
                 else:
                     picked = {n: arr[sel] for n, arr in host_arrays.items()}
-                stacked = {n: jax.device_put(np.asarray(arr, self.dtype))
-                           for n, arr in picked.items()}
+                host = {n: np.array(arr, self.dtype,
+                                    copy=True if self._copy_staged else None)
+                        for n, arr in picked.items()}
+                stacked = {n: jax.device_put(a) for n, a in host.items()}
+                nbytes = sum(a.nbytes for a in host.values())
+                self.h2d_bytes += nbytes
+                if stats is not None:
+                    stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + nbytes
                 slot_arr = jnp.array(slots, jnp.int32)
                 self.bufs = self._insert(self.bufs, stacked, slot_arr)
                 for k, s in zip(fresh, slots):
@@ -260,7 +277,8 @@ class ExpertCache:
 
     def reset_stats(self):
         with self.lock:
-            self.hits = self.misses = self.evictions = self.prefetch_evicted = 0
+            self.hits = self.misses = self.evictions = 0
+            self.prefetch_evicted = self.h2d_bytes = 0
 
     def check_invariants(self) -> bool:
         """Property-test hook: page table and LRU agree, no slot aliasing,

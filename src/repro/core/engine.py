@@ -205,8 +205,9 @@ class Request:
 # snapshot, the per-request delta, and the legacy stats dict all iterate
 # (each name is also a Metrics field)
 RUNTIME_COUNTER_KEYS = ("lookups", "hits", "on_demand_loads", "prefetched",
-                        "evictions", "prefetch_evicted_unused", "host_syncs",
-                        "verify_blocks", "fast_blocks", "fast_fallbacks",
+                        "evictions", "prefetch_evicted_unused", "h2d_bytes",
+                        "host_syncs", "verify_syncs", "verify_blocks",
+                        "fast_blocks", "fast_fallbacks",
                         "iterations", "drafted", "accepted",
                         # resilience plane (prefetcher/store health)
                         "prefetch_errors", "prefetch_retries",
@@ -225,7 +226,6 @@ class Metrics:
     snapshots and the cumulative view stay consistent under addition."""
     requests: int = 0
     tokens: int = 0
-    wall_s: float = 0.0
     iterations: int = 0
     drafted: int = 0
     accepted: int = 0
@@ -236,7 +236,9 @@ class Metrics:
     prefetched: int = 0
     evictions: int = 0
     prefetch_evicted_unused: int = 0
-    host_syncs: int = 0
+    h2d_bytes: int = 0                  # expert bytes handed to device_put
+    host_syncs: int = 0                 # every device->host sync of a turn
+    verify_syncs: int = 0               # those made verifying / accepting
     verify_blocks: int = 0
     fast_blocks: int = 0
     fast_fallbacks: int = 0
@@ -250,10 +252,6 @@ class Metrics:
     cutoff_layer: int = -1              # configuration echo, not a counter
 
     # ------------------------------------------------------------- derived
-    @property
-    def tpot_wall(self) -> float:
-        return self.wall_s / max(self.tokens, 1)
-
     @property
     def acceptance_rate(self) -> float:
         return self.accepted / max(self.drafted, 1)
@@ -271,14 +269,13 @@ class Metrics:
         """Accumulate ``other`` into self (cumulative view)."""
         for f in _COUNTERS:
             setattr(self, f, getattr(self, f) + getattr(other, f))
-        self.wall_s += other.wall_s
         if other.cutoff_layer >= 0:      # -1 = "no offload plane": adding a
             self.cutoff_layer = other.cutoff_layer  # default-constructed
         return self                      # Metrics must not wipe the echo
 
     def as_dict(self) -> Dict[str, float]:
         d = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)}
-        d.update(tpot_wall=self.tpot_wall, acceptance_rate=self.acceptance_rate,
+        d.update(acceptance_rate=self.acceptance_rate,
                  hit_rate=self.hit_rate,
                  tokens_per_iteration=self.tokens_per_iteration)
         return d
@@ -312,7 +309,7 @@ class Session:
     Owns the per-request plane: the committed-chunk generator — whose frame
     holds the KV/draft/decode state, lazily started on the first ``turn`` so
     admission order is scheduler-controlled — the emitted-token buffer, the
-    finish reason, the wall clock, and a counter-delta LEDGER.  The ledger
+    finish reason, and a counter-delta LEDGER.  The ledger
     accrues per-turn deltas of the engine-global cumulative counters; a
     single before/after snapshot (how PR 3's serial ``stream`` computed
     per-request metrics) would charge one session with every other session's
@@ -351,7 +348,6 @@ class Session:
                                  self.sstats)
         self.ledger: Dict[str, int] = {k: 0 for k in RUNTIME_COUNTER_KEYS}
         self.emitted: List[int] = []
-        self.wall = 0.0                 # decode-side time, not consumer time
         self.result: Optional[GenerationResult] = None
         # per-request deadline: armed on the first decode turn so queueing
         # time behind a long backlog doesn't consume the request's budget
@@ -381,14 +377,12 @@ class Session:
             self._finalize(reason)
 
     def _step(self, fn):
-        """Run one decode-side step under this session's wall clock and
-        counter ledger (per-turn engine-counter deltas)."""
+        """Run one decode-side step under this session's counter ledger
+        (per-turn engine-counter deltas)."""
         before = self.engine._counters()
-        t0 = time.perf_counter()
         try:
             return fn()
         finally:
-            self.wall += time.perf_counter() - t0
             after = self.engine._counters()
             for k in self.ledger:
                 self.ledger[k] += after.get(k, 0) - before.get(k, 0)
@@ -399,8 +393,9 @@ class Session:
         rt = self.engine.runtime
         if rt is not None:
             if self.dstate is None:
-                self.dstate = rt.start_session(self._prompt,
-                                               self.request.max_new_tokens)
+                self.dstate = rt.start_session(
+                    self._prompt, self.request.max_new_tokens,
+                    request_id=self.request.request_id)
             return rt.session_turn(self.dstate)
         try:
             return next(self.gen)
@@ -435,13 +430,10 @@ class Session:
             return None
         return self._commit_chunk(chunk)
 
-    def deliver(self, chunk, delta: Dict[str, int],
-                wall: float) -> Optional[List[int]]:
+    def deliver(self, chunk, delta: Dict[str, int]) -> Optional[List[int]]:
         """Commit a chunk produced by a batched cross-session round
         (``OffloadEngine.session_turns``): fold the round's per-session
-        counter delta and this session's own decode wall time (measured
-        per-phase by the runtime — a batchmate's miss fallback is not
-        charged here) into the ledger, then run the same
+        counter delta into the ledger, then run the same
         stop-token/finalize logic as a solo :meth:`turn`.  A chunk that is
         an :class:`ExpertLoadError` (this session's block could not load
         its experts even synchronously) retires the session with
@@ -450,7 +442,6 @@ class Session:
             return None
         for k in self.ledger:
             self.ledger[k] += delta.get(k, 0)
-        self.wall += wall
         if isinstance(chunk, ExpertLoadError):
             self._finalize("io_error")
             return None
@@ -481,7 +472,7 @@ class Session:
 
     def _finalize(self, finish: str):
         self._step(self._close_decode)  # offload path retires its DecodeState
-        m = Metrics(requests=1, tokens=len(self.emitted), wall_s=self.wall,
+        m = Metrics(requests=1, tokens=len(self.emitted),
                     cutoff_layer=self.engine.cutoff_layer)
         if self.engine.runtime is not None:
             for k, v in self.ledger.items():
@@ -558,9 +549,7 @@ class Engine:
         the request's :class:`GenerationResult` is at ``self.last_result``.
         If the consumer abandons the generator mid-stream the request is
         retired with ``finish_reason="aborted"`` and the engine stays warm
-        and reusable.  wall_s accumulates only decode-side time (inside the
-        chunk generator), not consumer time between yields — so streamed
-        and one-shot requests report comparable per-request latency."""
+        and reusable."""
         session = Session(self, request)
         try:
             while True:
@@ -586,7 +575,7 @@ class Engine:
         With an offload runtime, each scheduling round gathers the ready
         sessions' draft blocks into ONE fused cross-session verify dispatch
         (one routing pass, one page-table gather, one ``cache_moe`` launch,
-        ≤2 host syncs per ROUND instead of 2 per session) — concurrency
+        ≤2 verify syncs per ROUND instead of 2 per session) — concurrency
         makes the hot path cheaper than serial, not merely not-worse.  A
         session that misses falls back alone without dragging its
         batchmates off the fast path.
@@ -624,7 +613,7 @@ class Engine:
                         s.cancel("deadline")
                 # batched cross-session round: every started runtime session
                 # advances through ONE fused verify dispatch (one routing
-                # pass / table gather / cache_moe launch, ≤2 host syncs for
+                # pass / table gather / cache_moe launch, ≤2 verify syncs for
                 # the whole round); fresh admissions run their prefill solo
                 # first, and non-offload engines always turn solo.
                 round_sts = [s for _, s in active
@@ -633,8 +622,8 @@ class Engine:
                 if round_sts:
                     res = self.runtime.session_turns(
                         [s.dstate for s in round_sts])
-                    for s, (chunk, delta, wall) in zip(round_sts, res):
-                        delivered[id(s)] = s.deliver(chunk, delta, wall)
+                    for s, (chunk, delta) in zip(round_sts, res):
+                        delivered[id(s)] = s.deliver(chunk, delta)
                 for name, s in list(active):
                     chunk = delivered[id(s)] if id(s) in delivered \
                         else s.turn()

@@ -91,7 +91,8 @@ class HostExpertStore:
         The returned arrays are views into the calling thread's current
         staging buffer; they stay valid until that thread's next-but-one
         ``fetch`` (double buffering) — long enough for
-        ``ExpertCache.insert`` to dispatch the H2D transfer.
+        ``ExpertCache.insert`` to dispatch the H2D transfer, which copies
+        them first where ``device_put`` would alias them (the CPU).
         """
         if self.chaos is not None:
             self.chaos.on_fetch(len(keys))     # may spike (sleep) or raise

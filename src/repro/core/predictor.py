@@ -11,7 +11,7 @@ coarse-grained (MoE-Infinity) / gating-based strategies.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,23 +34,17 @@ class ExpertPredictor:
             lambda g, h: jax.lax.top_k(
                 jax.nn.softmax(h.astype(jnp.float32) @ g, axis=-1), self.k))
 
-    def predict_layer(self, layer: int, tap: jax.Array
+    def predict_layer(self, layer: int, tap: jax.Array,
+                      readback: Callable[[jax.Array], np.ndarray]
                       ) -> List[ExpertKey]:
         """tap: [B, 1, d] draft gate-input for layer ``layer`` -> predicted
-        critical experts of the corresponding target layer."""
-        h = np.asarray(tap).reshape(-1, tap.shape[-1])
+        critical experts of the corresponding target layer.  Both device->
+        host transfers go through ``readback``: the serving runtime passes
+        its counted sync point, so no caller syncs uncounted by default."""
+        h = readback(tap).reshape(-1, tap.shape[-1])
         _, ids = self._score(self.gates[layer], jnp.asarray(h))
-        uniq = list(dict.fromkeys(int(i) for i in np.asarray(ids).ravel()))
+        uniq = list(dict.fromkeys(int(i) for i in readback(ids).ravel()))
         return [(layer, e) for e in uniq[: self.k]]
-
-    def predict_all(self, taps: jax.Array, cutoff: int) -> List[ExpertKey]:
-        """taps: [L, B, 1, d] (one draft step) -> predictions for layers
-        0..cutoff, shallow layers first (just-in-time ordering)."""
-        out: List[ExpertKey] = []
-        L = min(cutoff + 1, self.num_layers, taps.shape[0])
-        for l in range(L):
-            out.extend(self.predict_layer(l, taps[l]))
-        return out
 
 
 # ---------------------------------------------------------------------------

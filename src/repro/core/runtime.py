@@ -27,10 +27,11 @@ the slot-indexed grouped kernel ``kernels/cache_moe.py``:
   sync in the block is reading that one scalar.  If every routed expert was
   cache-resident (the common case once prefetching is warm) the block's
   logits and KV-cache update are committed as-is — together with the
-  accept/reject readback in ``generate`` that is **2 host syncs per verify
-  block**.  If some expert was missing, the speculative fast block is
-  discarded (its KV cache is a pure-functional copy, so nothing to undo) and
-  the slow path re-runs the block with on-demand loading.
+  accept/reject readback that is 2 verify syncs per block (the
+  ``verify_syncs`` contract below).  If some expert was missing, the
+  speculative fast block is discarded (its KV cache is a pure-functional
+  copy, so nothing to undo) and the slow path re-runs the block with
+  on-demand loading.
 
 * **slow path (miss resolution)** — the layer-by-layer loop: routing ids are
   read back once per layer (the miss-resolution sync), missing experts are
@@ -51,11 +52,24 @@ Baseline policies (for the paper's comparisons) plug into the same loop:
   synchronous prefetch — always the slow path, per its design).
 
 Host-sync accounting: every blocking device->host readback on the DECODE
-path goes through ``_readback`` (a test hook — tests/test_offload_hotpath.py
-spies on it to enforce the ≤2-syncs-per-block contract) and is counted in
-the ``host_syncs`` counter.  Metrics-plane readbacks (each session's
-device-side fast-hit accumulator, committed by ``finish_session`` once at
-retirement) sit outside the decode loop and are intentionally not counted.
+path goes through ``_readback`` and is counted in ``host_syncs`` — the
+draft loop's argmax, both of the predictor's readbacks per predicted layer,
+the prefill's first token, the slow path's routing ids and the verify
+readbacks alike (tests/test_tracing.py checks the count against every
+conversion of a jax array during a turn).  ``verify_syncs`` counts the
+subset made while verifying and accepting a block, and carries the
+contract: ≤2 per verify block (the all-hit flag and the accept argmax, 1
+inside a fast block) and ≤2 per fused round.  Metrics-plane readbacks
+(each session's device-side fast-hit accumulator, committed by
+``finish_session`` once at retirement) sit outside the decode loop and are
+intentionally not counted.
+
+Stage spans: ``jax.profiler.TraceAnnotation`` at the call sites names each
+serving stage on the profiler's host timeline, on the device ops' clock —
+``spmoe.prefill`` / ``spmoe.draft`` / ``spmoe.verify`` /
+``spmoe.verify_round`` / ``spmoe.load_wave``, all opened by the serving
+thread, so they nest.  With no profiler active a span costs its context
+manager only (its arguments are encoded only while tracing).
 
 This engine is the *internal* offload layer: construct it from an
 ``EngineConfig`` (core/engine.py) — the public request/stream API is
@@ -82,8 +96,8 @@ scheduling round at once — each ready session drafts sequentially, then
 every armed session's block is verified in ONE fused dispatch
 (``_verify_fast_batched``: per-session attention against each session's own
 KV cache, but one concatenated [ΣT_i, ·] row batch through routing, the
-``table_dev`` gather, the ``cache_moe`` kernel and the head), with ≤2 host
-syncs for the whole round instead of 2·N.  Row-wise ops are bit-stable
+``table_dev`` gather, the ``cache_moe`` kernel and the head), with ≤2
+verify syncs for the whole round instead of 2·N.  Row-wise ops are bit-stable
 under concatenation, so batched rounds stay lossless — bit-identical to
 serving every session alone; a session that misses falls back alone to the
 slow path without dragging its batchmates off the fast path.  Per-session
@@ -142,6 +156,7 @@ class DecodeState:
     residency."""
     max_new: int
     tcache: Any
+    request_id: Optional[str] = None  # span argument, never read otherwise
     dcache: Any = None
     cur: Optional[jax.Array] = None
     pos: int = 0
@@ -164,7 +179,8 @@ class DecodeState:
     # prefetched/evictions metrics, which mis-attributed async loads landing
     # between two sessions' turns (ROADMAP open item, closed).
     io: Dict[str, int] = dataclasses.field(default_factory=lambda: {
-        "prefetched": 0, "evictions": 0, "prefetch_evicted_unused": 0})
+        "prefetched": 0, "evictions": 0, "prefetch_evicted_unused": 0,
+        "h2d_bytes": 0})
 
 
 class OffloadEngine:
@@ -235,6 +251,7 @@ class OffloadEngine:
         self.layer_lookups = 0
         self.on_demand_loads = 0
         self.host_syncs = 0
+        self.verify_syncs = 0
         self.verify_blocks = 0
         self.fast_blocks = 0
         self.fast_fallbacks = 0
@@ -274,11 +291,15 @@ class OffloadEngine:
             self._precompile_fast()
 
     # ------------------------------------------------------------------ sync
-    def _readback(self, x):
-        """The ONLY device->host sync point in the engine.  Every blocking
-        transfer funnels through here so tests can spy on it and the stats
-        report an honest host-sync count."""
+    def _readback(self, x, verify: bool = False):
+        """The decode path's device->host sync point: every blocking
+        transfer of a turn funnels through here, so ``host_syncs`` counts
+        them all.  ``verify`` marks a sync made while verifying or accepting
+        a block; those alone count in ``verify_syncs``, the counter that
+        carries the ≤2-per-block / ≤2-per-round contract."""
         self.host_syncs += 1
+        if verify:
+            self.verify_syncs += 1
         return np.asarray(x)
 
     # ------------------------------------------------------------------ jit
@@ -575,19 +596,21 @@ class OffloadEngine:
         attempts = self.config.io_retries + 1
         verify = self.prefetcher.verify
         last: Optional[BaseException] = None
-        for a in range(attempts):
-            calm = self.chaos.calm() if self.chaos is not None \
-                and a == attempts - 1 else contextlib.nullcontext()
-            try:
-                with calm:
-                    arrays = self.store.fetch_verified(wave) if verify \
-                        else self.store.fetch(wave)
-                    return self.cache.insert(wave, arrays, mark_used=True,
-                                             stats=st.io)
-            except OSError as e:           # ChaosError/PayloadCorruption too
-                last = e
-                if a < attempts - 1:
-                    time.sleep(self.config.retry_backoff_s * (2 ** a))
+        with jax.profiler.TraceAnnotation("spmoe.load_wave",
+                                          request_id=st.request_id):
+            for a in range(attempts):
+                calm = self.chaos.calm() if self.chaos is not None \
+                    and a == attempts - 1 else contextlib.nullcontext()
+                try:
+                    with calm:
+                        arrays = self.store.fetch_verified(wave) if verify \
+                            else self.store.fetch(wave)
+                        return self.cache.insert(wave, arrays, mark_used=True,
+                                                 stats=st.io)
+                except OSError as e:       # ChaosError/PayloadCorruption too
+                    last = e
+                    if a < attempts - 1:
+                        time.sleep(self.config.retry_backoff_s * (2 ** a))
         self.io_errors += 1
         raise ExpertLoadError(
             f"on-demand load of {len(wave)} experts failed after "
@@ -609,7 +632,7 @@ class OffloadEngine:
                 logits, ok, ncache, nhist, nact = self._verify_fast(
                     self._rp, bufs, table, st.history_dev, tokens, pos,
                     tcache)
-            if bool(self._readback(ok)):          # sync 1 of ≤2 per block
+            if bool(self._readback(ok, verify=True)):   # 1 of ≤2 a block
                 st.history_dev = nhist
                 st.fast_active_dev = st.fast_active_dev + nact
                 st.fast_blocks += 1
@@ -640,7 +663,7 @@ class OffloadEngine:
             x, ncl, h2 = self._attn_half(lp, x, cl, pos)
             new_layers.append(ncl)
             w, ids, probs = self._gate(lp["moe"]["gate"], h2)
-            ids_np = self._readback(ids)          # miss-resolution sync
+            ids_np = self._readback(ids, verify=True)   # miss resolution
             act = np.zeros((cfg.num_experts,), np.float32)
             act[np.unique(ids_np)] = 1.0
             st.history_dev = self._hist_add(st.history_dev, l,
@@ -648,22 +671,26 @@ class OffloadEngine:
             # AdapMoE baseline: predict next layer from *this* layer's gate
             # input using the target's own gates, synchronous prefetch.
             if self.policy == "adapmoe" and l + 1 < self.store.num_layers:
-                nxt = self.predictor.predict_layer(l + 1, h2[:, -1:])
+                nxt = self.predictor.predict_layer(
+                    l + 1, h2[:, -1:],
+                    functools.partial(self._readback, verify=True))
                 _, miss = self.cache.lookup(nxt, touch=False)
                 if miss:
                     self._prefetch(st, miss)         # vanilla mode: blocking
-            hits, misses = self._ensure_loaded(l, ids_np)
-            total_misses += len(misses)
-            # cached-first compute (dispatches async under jax): hit experts'
-            # slots unmasked, everything else -1
-            slot_lut = np.full((cfg.num_experts,), -1, np.int64)
-            for (_, e), s in hits.items():
-                slot_lut[e] = s
             xf = h2.reshape(T, cfg.d_model)
+            # lookup through dispatch under the cache lock: a prefetch insert
+            # in between could evict a hit and refill its slot
             with self.cache.lock:
+                hits, misses = self._ensure_loaded(l, ids_np)
+                # cached-first compute (dispatches async under jax): hit
+                # experts' slots unmasked, everything else -1
+                slot_lut = np.full((cfg.num_experts,), -1, np.int64)
+                for (_, e), s in hits.items():
+                    slot_lut[e] = s
                 bufs, _ = self.cache.snapshot()
                 y = self._moe_apply(bufs, xf,
                                     jnp.asarray(slot_lut[ids_np], jnp.int32), w)
+            total_misses += len(misses)
             if misses:
                 # on-demand batched loads, in cache-capacity-bounded waves:
                 # each wave's experts are loaded (evicting as needed — the
@@ -673,11 +700,14 @@ class OffloadEngine:
                 wave_size = max(1, self.cache.num_slots)
                 for w0 in range(0, len(misses), wave_size):
                     wave = misses[w0:w0 + wave_size]
-                    slots = self._load_wave(wave, st)
-                    wave_lut = np.full((cfg.num_experts,), -1, np.int64)
-                    for (key, s) in zip(wave, slots):
-                        wave_lut[key[1]] = s
+                    # insert through dispatch under the cache lock, so a
+                    # prefetch insert cannot evict the wave first (the
+                    # worker's own fetches go on meanwhile)
                     with self.cache.lock:
+                        slots = self._load_wave(wave, st)
+                        wave_lut = np.full((cfg.num_experts,), -1, np.int64)
+                        for (key, s) in zip(wave, slots):
+                            wave_lut[key[1]] = s
                         bufs, _ = self.cache.snapshot()
                         y = y + self._moe_apply(
                             bufs, xf,
@@ -702,16 +732,18 @@ class OffloadEngine:
     # with finish_session.  All three re-bind self._st, so any number of
     # sessions may interleave turns on the one warm cache.
 
-    def start_session(self, prompt: jax.Array, max_new_tokens: int
-                      ) -> DecodeState:
+    def start_session(self, prompt: jax.Array, max_new_tokens: int,
+                      request_id: Optional[str] = None) -> DecodeState:
         """Admit one request: allocate its per-session plane (KV cache,
         draft cache, request-level history, fast-path arming seeded from the
         engine's warm hint) and run the prefill verify block — through the
-        cache-aware path, so its expert loads warm the shared cache."""
+        cache-aware path, so its expert loads warm the shared cache.
+        ``request_id`` only labels the session's spans."""
         assert prompt.shape[0] == 1
         st = DecodeState(
             max_new=max_new_tokens,
             tcache=self.target.init_cache(1, self.max_seq),
+            request_id=request_id,
             n=self.draft_len,                     # 0 for greedy decode
             history_dev=jnp.zeros(self._hist_shape, jnp.float32),
             fast_active_dev=jnp.zeros((), jnp.float32),
@@ -720,14 +752,16 @@ class OffloadEngine:
             st.finished = True
             return st
         self._st = st
-        if st.n > 0:
-            _, st.dcache = self.draft.prefill(self.dparams, prompt,
-                                              self.max_seq)
-        logits, st.tcache = self._verify_block(prompt, 0, st.tcache)
-        st.cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+        with jax.profiler.TraceAnnotation("spmoe.prefill",
+                                          request_id=request_id):
+            if st.n > 0:
+                _, st.dcache = self.draft.prefill(self.dparams, prompt,
+                                                  self.max_seq)
+            logits, st.tcache = self._verify_block(prompt, 0, st.tcache)
+            st.cur = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+            st.pending = [int(self._readback(st.cur, verify=True)[0, 0])]
         st.pos = prompt.shape[1]
         st.emitted_total = 1
-        st.pending = [int(st.cur[0, 0])]
         return st
 
     # sentinel: _turn_early found nothing to deliver — the turn must draft
@@ -755,35 +789,39 @@ class OffloadEngine:
         history prefetch, the draft loop with SP-MoE speculative prefetching,
         and the assembled verify block.  Returns (drafts, block [1, N+1])."""
         N = st.n
-        # MoE-Infinity: request-level historical prefetch, all layers
-        if self.policy == "moe-infinity":
-            hist = self._readback(st.history_dev)
-            for l in range(self.store.num_layers):
-                top = np.argsort(-hist[l])[: self.k]
-                keys = [(l, int(e)) for e in top]
-                # while the fast verify path is armed it never touches the
-                # LRU itself (that would need a device readback), so
-                # predicted-hot experts carry the recency signal instead
-                _, miss = self.cache.lookup(keys, touch=st.fast_ok)
-                if miss:
-                    self._prefetch(st, miss)
-        # ---- drafting stage (+ SP-MoE speculative prefetching) ----
         drafts = []
-        tok = st.cur
-        for i in range(N):
-            lg, st.dcache, taps = self._draft_step(
-                self.dparams, st.dcache, tok, jnp.int32(st.pos + i))
-            tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
-            drafts.append(int(tok[0, 0]))
-            if self.policy == "spmoe" and self.cutoff >= 0:
-                tap_stack = self._draft_taps_for_moe(taps)
-                for l in range(min(self.cutoff + 1, self.store.num_layers)):
-                    keys = self.predictor.predict_layer(l, tap_stack[l])
-                    # see moe-infinity note: predictions substitute for LRU
-                    # touches while the fast path is armed
+        with jax.profiler.TraceAnnotation("spmoe.draft",
+                                          request_id=st.request_id):
+            # MoE-Infinity: request-level historical prefetch, all layers
+            if self.policy == "moe-infinity":
+                hist = self._readback(st.history_dev)
+                for l in range(self.store.num_layers):
+                    top = np.argsort(-hist[l])[: self.k]
+                    keys = [(l, int(e)) for e in top]
+                    # while the fast verify path is armed it never touches
+                    # the LRU itself (that would need a device readback), so
+                    # predicted-hot experts carry the recency signal instead
                     _, miss = self.cache.lookup(keys, touch=st.fast_ok)
                     if miss:
                         self._prefetch(st, miss)
+            # ---- drafting stage (+ SP-MoE speculative prefetching) ----
+            tok = st.cur
+            for i in range(N):
+                lg, st.dcache, taps = self._draft_step(
+                    self.dparams, st.dcache, tok, jnp.int32(st.pos + i))
+                tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
+                drafts.append(int(self._readback(tok)[0, 0]))
+                if self.policy == "spmoe" and self.cutoff >= 0:
+                    tap_stack = self._draft_taps_for_moe(taps)
+                    for l in range(min(self.cutoff + 1,
+                                       self.store.num_layers)):
+                        keys = self.predictor.predict_layer(
+                            l, tap_stack[l], self._readback)
+                        # see moe-infinity note: predictions substitute for
+                        # LRU touches while the fast path is armed
+                        _, miss = self.cache.lookup(keys, touch=st.fast_ok)
+                        if miss:
+                            self._prefetch(st, miss)
         block = jnp.concatenate(
             [st.cur, jnp.asarray([drafts], jnp.int32)], axis=1) \
             if drafts else st.cur
@@ -829,16 +867,20 @@ class OffloadEngine:
             self.degraded_rounds += 1
         self._st = st
         drafts, block = self._turn_draft(st)
-        try:
-            tlogits, st.tcache = self._verify_block(block, st.pos, st.tcache)
-        except ExpertLoadError:
-            # the ladder's last rung: this session cannot make progress
-            # without the failed load — end it (the caller maps this to
-            # finish_reason="io_error"); batchmates are unaffected.
-            st.finished = True
-            raise
-        greedy = self._readback(jnp.argmax(tlogits, -1))[0]      # accept
-        return self._turn_commit(st, drafts, greedy)
+        with jax.profiler.TraceAnnotation("spmoe.verify",
+                                          request_id=st.request_id):
+            try:
+                tlogits, st.tcache = self._verify_block(block, st.pos,
+                                                        st.tcache)
+            except ExpertLoadError:
+                # the ladder's last rung: this session cannot make progress
+                # without the failed load — end it (the caller maps this to
+                # finish_reason="io_error"); batchmates are unaffected.
+                st.finished = True
+                raise
+            greedy = self._readback(jnp.argmax(tlogits, -1),
+                                    verify=True)[0]               # accept
+            return self._turn_commit(st, drafts, greedy)
 
     def _counter_delta(self, before: Dict[str, int]) -> Dict[str, int]:
         after = self.counters()
@@ -850,14 +892,13 @@ class OffloadEngine:
             into[k] = into.get(k, 0) + v
 
     def session_turns(self, sts: Sequence[DecodeState]
-                      ) -> List[Tuple[Optional[List[int]], Dict[str, int],
-                                      float]]:
+                      ) -> List[Tuple[Optional[List[int]], Dict[str, int]]]:
         """Advance SEVERAL sessions by one committed verify block each in a
         single scheduling round, verifying the ready sessions' blocks with
         ONE fused fast-path dispatch (``_verify_fast_batched``): one routing
         pass, one ``table_dev`` gather, one ``cache_moe`` launch, and ≤2
-        host syncs for the whole round — the per-session all-hit vector and
-        the accept/reject argmax, each read back once — instead of 2·N.
+        verify syncs for the whole round — the per-session all-hit vector
+        and the accept/reject argmax, each read back once — instead of 2·N.
 
         Per-session drafting (and its prefetch submissions) stays
         sequential ahead of the fused verify; sessions whose fast path is
@@ -867,30 +908,24 @@ class OffloadEngine:
         fused results commit untouched.  Per-session results are
         bit-identical to ``session_turn`` serving each session by itself.
 
-        Returns one ``(chunk, counter_delta, wall_s)`` triple per session:
+        Returns one ``(chunk, counter_delta)`` pair per session:
         ``counter_delta`` attributes this round's cumulative-counter growth
         to the session that caused it (the ≤2 shared round syncs are charged
         to the round's first fused session so the per-request ledgers still
-        tile the cumulative counters exactly), and ``wall_s`` is the decode
-        time this session's own phases took — a fallback's slow re-run is
-        charged to the session that missed, only the genuinely shared fused
-        dispatch is split evenly across its members."""
+        tile the cumulative counters exactly)."""
         # a chunk is List[int], None (session done), or an ExpertLoadError
         # instance (session ended by the ladder's io_error rung)
         chunks: List[Any] = [None] * len(sts)
         deltas: List[Dict[str, int]] = [{} for _ in sts]
-        walls: List[float] = [0.0] * len(sts)
         pend: List[Tuple[int, DecodeState, List[int], jax.Array]] = []
         self._check_health()                 # one ladder step per round
         degraded_counted = False
         for i, st in enumerate(sts):
             before = self.counters()
-            t0 = time.perf_counter()
             early = self._turn_early(st)
             if early is not self._NEEDS_VERIFY:
                 chunks[i] = early
                 deltas[i] = self._counter_delta(before)
-                walls[i] += time.perf_counter() - t0
                 continue
             self._st = st
             if self._degraded and not degraded_counted:
@@ -901,7 +936,6 @@ class OffloadEngine:
                 self.degraded_rounds += 1
             drafts, block = self._turn_draft(st)
             deltas[i] = self._counter_delta(before)
-            walls[i] += time.perf_counter() - t0
             pend.append((i, st, drafts, block))
         if pend:
             self.verify_rounds += 1
@@ -916,30 +950,31 @@ class OffloadEngine:
             fused.sort(key=lambda p: p[3].shape[1])
             fused_idx = {p[0] for p in fused}
             solo = [p for p in pend if p[0] not in fused_idx]
-            self._round_fused(fused, chunks, deltas, walls)
+            self._round_fused(fused, chunks, deltas)
         else:
             solo = pend
         for i, st, drafts, block in solo:
             before = self.counters()
-            t0 = time.perf_counter()
             self._st = st
             self.round_launches += 1
-            try:
-                tlogits, st.tcache = self._verify_block(block, st.pos,
-                                                        st.tcache)
-                greedy = self._readback(jnp.argmax(tlogits, -1))[0]
-                chunks[i] = self._turn_commit(st, drafts, greedy)
-            except ExpertLoadError as e:
-                # ladder's last rung: end ONLY this session — batchmates'
-                # turns proceed.  The scheduler maps the exception chunk to
-                # finish_reason="io_error" (see engine.Session.deliver).
-                st.finished = True
-                chunks[i] = e
+            with jax.profiler.TraceAnnotation("spmoe.verify",
+                                              request_id=st.request_id):
+                try:
+                    tlogits, st.tcache = self._verify_block(block, st.pos,
+                                                            st.tcache)
+                    greedy = self._readback(jnp.argmax(tlogits, -1),
+                                            verify=True)[0]
+                    chunks[i] = self._turn_commit(st, drafts, greedy)
+                except ExpertLoadError as e:
+                    # ladder's last rung: end ONLY this session — batchmates'
+                    # turns proceed.  The scheduler maps the exception chunk
+                    # to finish_reason="io_error" (see engine.Session.deliver)
+                    st.finished = True
+                    chunks[i] = e
             self._merge_delta(deltas[i], self._counter_delta(before))
-            walls[i] += time.perf_counter() - t0
-        return list(zip(chunks, deltas, walls))
+        return list(zip(chunks, deltas))
 
-    def _round_fused(self, fused, chunks, deltas, walls):
+    def _round_fused(self, fused, chunks, deltas):
         """The fused leg of one scheduling round: dispatch every armed
         session's block in one ``_verify_fast_batched`` call, read the
         per-session all-hit vector and the round's accept/reject argmax back
@@ -951,57 +986,56 @@ class OffloadEngine:
         for b in blocks:
             offs.append(offs[-1] + b.shape[1])
         self.round_launches += 1
-        t0 = time.perf_counter()
-        # snapshot + dispatch under the cache lock: a concurrent donating
-        # insert must not delete the buffer handle mid-dispatch.
-        with self.cache.lock:
-            bufs, table = self.cache.snapshot()
-            logits, ok_vec, new_tcaches, new_hists, nact_vec = \
-                self._verify_fast_batched(
-                    self._rp, bufs, table,
-                    tuple(st.history_dev for st in sts),
-                    tuple(blocks),
-                    tuple(st.pos for st in sts),
-                    tuple(st.tcache for st in sts))
-        ok = self._readback(ok_vec)                 # round sync 1 of ≤2
-        greedy = self._readback(jnp.argmax(logits, -1))[0]   # round sync 2
-        shared = (time.perf_counter() - t0) / len(fused)
-        for i in idxs:      # the fused dispatch is genuinely shared work
-            walls[i] += shared
-        deltas[idxs[0]]["host_syncs"] = \
-            deltas[idxs[0]].get("host_syncs", 0) + 2
-        for j, (i, st, drafts, _) in enumerate(fused):
-            before = self.counters()
-            t0 = time.perf_counter()
-            self._st = st
-            self.verify_blocks += 1
-            if bool(ok[j]):
-                st.history_dev = new_hists[j]
-                st.fast_active_dev = st.fast_active_dev + nact_vec[j]
-                st.fast_blocks += 1
-                self.fast_blocks += 1
-                st.tcache = new_tcaches[j]
-                chunks[i] = self._turn_commit(
-                    st, drafts, greedy[offs[j]:offs[j + 1]])
-            else:
-                # mispredicted availability: this session falls back alone;
-                # its speculative tcache/history copies are discarded
-                st.fast_ok = False
-                st.fast_penalty = 2
-                self._fast_hint = False
-                self.fast_fallbacks += 1
-                self.round_launches += 1
-                try:
-                    tlogits, st.tcache = self._verify_block_slow(
-                        blocks[j], st.pos, st.tcache)
-                    g = self._readback(jnp.argmax(tlogits, -1))[0]
-                    chunks[i] = self._turn_commit(st, drafts, g)
-                except ExpertLoadError as e:
-                    # end ONLY this session; its fused batchmates committed
-                    st.finished = True
-                    chunks[i] = e
-            self._merge_delta(deltas[i], self._counter_delta(before))
-            walls[i] += time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("spmoe.verify_round"):
+            # snapshot + dispatch under the cache lock: a concurrent donating
+            # insert must not delete the buffer handle mid-dispatch.
+            with self.cache.lock:
+                bufs, table = self.cache.snapshot()
+                logits, ok_vec, new_tcaches, new_hists, nact_vec = \
+                    self._verify_fast_batched(
+                        self._rp, bufs, table,
+                        tuple(st.history_dev for st in sts),
+                        tuple(blocks),
+                        tuple(st.pos for st in sts),
+                        tuple(st.tcache for st in sts))
+            ok = self._readback(ok_vec, verify=True)        # round sync 1
+            greedy = self._readback(jnp.argmax(logits, -1),
+                                    verify=True)[0]         # round sync 2
+            for k in ("host_syncs", "verify_syncs"):
+                deltas[idxs[0]][k] = deltas[idxs[0]].get(k, 0) + 2
+            for j, (i, st, drafts, _) in enumerate(fused):
+                before = self.counters()
+                self._st = st
+                self.verify_blocks += 1
+                if bool(ok[j]):
+                    st.history_dev = new_hists[j]
+                    st.fast_active_dev = st.fast_active_dev + nact_vec[j]
+                    st.fast_blocks += 1
+                    self.fast_blocks += 1
+                    st.tcache = new_tcaches[j]
+                    chunks[i] = self._turn_commit(
+                        st, drafts, greedy[offs[j]:offs[j + 1]])
+                else:
+                    # mispredicted availability: this session falls back
+                    # alone; its speculative tcache/history copies are
+                    # discarded
+                    st.fast_ok = False
+                    st.fast_penalty = 2
+                    self._fast_hint = False
+                    self.fast_fallbacks += 1
+                    self.round_launches += 1
+                    try:
+                        tlogits, st.tcache = self._verify_block_slow(
+                            blocks[j], st.pos, st.tcache)
+                        g = self._readback(jnp.argmax(tlogits, -1),
+                                           verify=True)[0]
+                        chunks[i] = self._turn_commit(st, drafts, g)
+                    except ExpertLoadError as e:
+                        # end ONLY this session; its fused batchmates
+                        # committed
+                        st.finished = True
+                        chunks[i] = e
+                self._merge_delta(deltas[i], self._counter_delta(before))
 
     def _prefetch(self, st: DecodeState, keys):
         """Submit a prefetch on behalf of ``st``, remembering the task so
@@ -1070,16 +1104,11 @@ class OffloadEngine:
         legacy (tokens, stats-dict) shape; stats are this call's counter
         deltas (identical to the old cumulative dict on a fresh engine)."""
         before = self.counters()
-        t0 = time.perf_counter()
         out: List[int] = []
         for chunk in self.generate_stream(prompt, max_new_tokens):
             out.extend(chunk)
-        dt = time.perf_counter() - t0
-        after = self.counters()
-        d = {k: after[k] - before[k] for k in _COUNTER_KEYS}
+        d = self._counter_delta(before)
         stats = {
-            "wall_s": dt,
-            "tpot_wall": dt / max(len(out), 1),
             "iterations": d["iterations"],
             "acceptance_rate": d["accepted"] / max(d["drafted"], 1),
             "hit_rate": d["hits"] / max(d["lookups"], 1),
@@ -1089,6 +1118,8 @@ class OffloadEngine:
             "prefetch_evicted_unused": d["prefetch_evicted_unused"],
             "cutoff_layer": self.cutoff,
             "host_syncs": d["host_syncs"],
+            "verify_syncs": d["verify_syncs"],
+            "h2d_bytes": d["h2d_bytes"],
             "verify_blocks": d["verify_blocks"],
             "fast_blocks": d["fast_blocks"],
             "fast_fallbacks": d["fast_fallbacks"],
@@ -1109,7 +1140,9 @@ class OffloadEngine:
             "prefetched": self.prefetcher.loaded_count,
             "evictions": self.cache.evictions,
             "prefetch_evicted_unused": self.cache.prefetch_evicted,
+            "h2d_bytes": self.cache.h2d_bytes,
             "host_syncs": self.host_syncs,
+            "verify_syncs": self.verify_syncs,
             "verify_blocks": self.verify_blocks,
             "fast_blocks": self.fast_blocks,
             "fast_fallbacks": self.fast_fallbacks,
@@ -1142,7 +1175,7 @@ class OffloadEngine:
         """Zero the cumulative counters (cache + prefetcher + engine) so a
         warmed engine can report clean steady-state numbers."""
         self.layer_hits = self.layer_lookups = 0
-        self.on_demand_loads = self.host_syncs = 0
+        self.on_demand_loads = self.host_syncs = self.verify_syncs = 0
         self.verify_blocks = self.fast_blocks = self.fast_fallbacks = 0
         self.iterations = self.drafted = self.accepted = 0
         self.verify_rounds = self.round_launches = 0

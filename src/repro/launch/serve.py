@@ -12,7 +12,7 @@ One Engine serves all ``--requests`` requests, so request 2+ hits a warm
 expert cache (watch ``hit_rate`` climb).  ``--concurrency N`` decodes up
 to N requests at once on that one warm cache — each scheduling round
 batches the ready sessions' verify blocks into ONE fused kernel launch
-(one routing pass, ≤2 host syncs per round instead of 2 per session), and
+(one routing pass, ≤2 verify syncs per round instead of 2 per session), and
 every stream stays bit-identical to serving it alone.  ``--stream`` prints
 tokens as each verify block commits (prefixed with the request id when
 concurrent); ``--stop-token`` ends a request early on every decode x
@@ -128,7 +128,7 @@ def main():
                          "cache (1 = serial).  Each scheduling round "
                          "batches the ready sessions' verify blocks into "
                          "ONE fused kernel launch — one routing pass and "
-                         "<=2 host syncs per round instead of per session "
+                         "<=2 verify syncs per round instead of per session "
                          "— while every stream stays bit-identical to "
                          "serving it alone")
     ap.add_argument("--draft-len", type=int, default=4)
@@ -235,7 +235,7 @@ def main():
                 report(res)
         cum = eng.metrics()
         print(f"cumulative: requests={cum.requests} tokens={cum.tokens} "
-              f"hit_rate={cum.hit_rate:.3f} tpot={cum.tpot_wall * 1e3:.1f}ms")
+              f"hit_rate={cum.hit_rate:.3f}")
         if eng.runtime is not None:
             # runtime counters, not the Metrics ledger: worker-thread
             # increments landing between turn windows still show up here

@@ -148,10 +148,11 @@ def test_missing_session_falls_back_alone(ms):
 
 def test_round_sync_contract_two_syncs_per_round(ms):
     """On the warm all-hit path a fused round serving two sessions performs
-    at most 2 host syncs TOTAL (the per-session all-hit vector and the
+    at most 2 verify syncs TOTAL (the per-session all-hit vector and the
     accept argmax, one readback each) — the solo contract was 2 per block,
     i.e. 2·N per round.  At least one round must commit both sessions'
-    blocks inside that budget."""
+    blocks inside that budget.  Drafting's syncs (host_syncs only) are
+    outside this contract."""
     _, _, _, _, prompts, refs = ms
     with _engine(ms) as eng:
         rt = eng.runtime
@@ -160,9 +161,9 @@ def test_round_sync_contract_two_syncs_per_round(ms):
         orig = rt.session_turns
 
         def spy(sts):
-            s0, b0 = rt.host_syncs, rt.fast_blocks
+            s0, b0 = rt.verify_syncs, rt.fast_blocks
             out = orig(sts)
-            per_round.append((rt.host_syncs - s0, rt.fast_blocks - b0))
+            per_round.append((rt.verify_syncs - s0, rt.fast_blocks - b0))
             return out
 
         rt.session_turns = spy
@@ -173,7 +174,7 @@ def test_round_sync_contract_two_syncs_per_round(ms):
     verifying = [(s, b) for s, b in per_round if b > 0]
     assert verifying, "no round committed a fast block"
     assert max(s for s, _ in verifying) <= 2, \
-        f"a round exceeded 2 host syncs: {per_round}"
+        f"a round exceeded 2 verify syncs: {per_round}"
     assert any(b == 2 for s, b in verifying if s <= 2), \
         f"no round committed both sessions within 2 syncs: {per_round}"
 

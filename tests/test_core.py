@@ -66,6 +66,31 @@ def test_cache_batched_insert_contents():
         np.testing.assert_array_equal(bufs[s], np.full((2,), i))
 
 
+def test_cache_insert_survives_the_staging_buffer_being_refilled():
+    """The caller's staging memory is refilled (HostExpertStore.fetch reuses
+    it two fetches later) while the insert's scatter still waits behind
+    earlier device work: the slot holds what was inserted.  On the CPU a
+    device_put of an aligned numpy array aliases it, so without a copy the
+    slot read the refilled bytes."""
+    cache = ExpertCache(4, {"w": (256, 256)}, jnp.float32)
+
+    @jax.jit
+    def slow(b):
+        for _ in range(60):
+            b = jnp.tanh(b @ b) * 0 + b
+        return b
+
+    raw = np.zeros(16 * 256 * 256 + 16, np.float32)
+    off = (-raw.ctypes.data % 64) // 4          # 64-byte aligned, as staged
+    staged = raw[off:off + 16 * 256 * 256].reshape(16, 256, 256)
+    staged[:1] = 1.0
+    cache.bufs = {n: slow(b) for n, b in cache.bufs.items()}
+    cache.insert([(0, 0)], {"w": staged[:1]})
+    staged[:1] = 2.0
+    slot = np.asarray(cache.bufs["w"][cache.table[(0, 0)]])
+    np.testing.assert_array_equal(slot, np.ones((256, 256), np.float32))
+
+
 # ---------------------------------------------------------------------------
 # cutoff solver (paper §3.2)
 # ---------------------------------------------------------------------------
@@ -191,7 +216,7 @@ def test_predictor_matches_gate_topk():
     cfg, target, tparams, eng = _toy_engine()
     pred = eng.runtime.predictor
     tap = jax.random.normal(jax.random.PRNGKey(3), (1, 1, cfg.d_model))
-    keys = pred.predict_layer(0, tap)
+    keys = pred.predict_layer(0, tap, np.asarray)
     # manual: top-k of softmax(tap @ gate_0)
     gate = np.asarray(tparams["layers"]["moe"]["gate"])[0]
     scores = np.asarray(tap).reshape(-1) @ gate

@@ -139,7 +139,7 @@ def test_metrics_isolated_under_interleaving(ms):
     # ledger completeness over the synchronously-updated counters (the
     # async I/O counters — prefetched/evictions — can land between turns)
     for k in ("iterations", "drafted", "accepted", "verify_blocks",
-              "fast_blocks", "fast_fallbacks", "host_syncs",
+              "fast_blocks", "fast_fallbacks", "host_syncs", "verify_syncs",
               "on_demand_loads", "lookups", "hits", "tokens", "requests"):
         assert sum(r.metrics[k] for r in res) == after[k] - before[k], k
 
@@ -150,11 +150,12 @@ def test_metrics_isolated_under_interleaving(ms):
 
 def test_sync_contract_two_syncs_per_block_with_concurrency(ms):
     """With an ample cache and two interleaved sessions: solo fast blocks
-    (the prefills) still perform exactly ONE host sync inside _verify_block,
-    and every batched decode ROUND — which commits BOTH sessions' verify
-    blocks in one fused dispatch — performs ≤2 host syncs total, i.e. the
-    old 2-per-block contract became 2-per-round.  Both streams stay
-    lossless."""
+    (the prefills) still perform exactly ONE verify sync inside
+    _verify_block, and every batched decode ROUND — which commits BOTH
+    sessions' verify blocks in one fused dispatch — performs ≤2 verify
+    syncs total, i.e. the old 2-per-block contract became 2-per-round.
+    Drafting's syncs count in host_syncs only, outside this contract.  Both
+    streams stay lossless."""
     _, _, _, _, prompts, refs = ms
     with _engine(ms) as eng:
         rt = eng.runtime
@@ -164,16 +165,16 @@ def test_sync_contract_two_syncs_per_block_with_concurrency(ms):
         orig_turns = rt.session_turns
 
         def spy_vb(tokens, pos, tcache):
-            before_sync, before_fast = rt.host_syncs, rt.fast_blocks
+            before_sync, before_fast = rt.verify_syncs, rt.fast_blocks
             out = orig_vb(tokens, pos, tcache)
-            per_block.append((rt.host_syncs - before_sync,
+            per_block.append((rt.verify_syncs - before_sync,
                               rt.fast_blocks > before_fast))
             return out
 
         def spy_turns(sts):
-            before_sync, before_fast = rt.host_syncs, rt.fast_blocks
+            before_sync, before_fast = rt.verify_syncs, rt.fast_blocks
             out = orig_turns(sts)
-            per_round.append((rt.host_syncs - before_sync,
+            per_round.append((rt.verify_syncs - before_sync,
                               rt.fast_blocks - before_fast))
             return out
 
@@ -190,7 +191,7 @@ def test_sync_contract_two_syncs_per_block_with_concurrency(ms):
     fused = [(s, b) for s, b in per_round if b == 2]
     assert fused, "no round committed both sessions' blocks fused"
     assert max(s for s, _ in fused) <= 2, \
-        f"a fused round exceeded 2 host syncs: {per_round}"
+        f"a fused round exceeded 2 verify syncs: {per_round}"
     assert all(r.metrics.fast_fallbacks == 0 for r in res)
 
 
