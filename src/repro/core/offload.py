@@ -1,10 +1,14 @@
 """Host-resident expert store (the offloaded side of the cache).
 
 All expert weights stay in host memory for the lifetime of the engine —
-eviction never copies back (paper §7).  ``fetch`` performs the batched read
-into **preallocated, contiguous staging buffers** (the pinned-memory analogue
-on this backend): one ``np.take(..., out=...)`` per weight tensor, no
-per-call allocation, no fancy-indexed temporary.  Per *thread*, two staging
+eviction never copies back (paper §7).  ``fetch`` copies each requested
+expert's rows straight into **preallocated, contiguous staging buffers** (the
+pinned-memory analogue on this backend): one ``np.copyto`` per key and
+weight tensor, no per-call allocation, no temporary of the batch's size
+(``np.take(..., out=...)`` with its default ``mode="raise"`` would gather
+into a fresh batch-sized temporary, then copy that into ``out``).  Both
+parts of every key are range-checked first, so a bad layer or expert id
+raises instead of reading a neighbouring expert.  Per *thread*, two staging
 buffers alternate (double buffering) so the H2D transfer dispatched by
 ``ExpertCache.insert`` on batch *i* overlaps the host gather of batch *i+1*
 — the prefetch worker's pipeline never stalls on its own staging memory.
@@ -85,6 +89,16 @@ class HostExpertStore:
     def expert_bytes(self) -> int:
         return int(sum(self._store[n][0, 0].nbytes for n in self.names))
 
+    def _row(self, key: ExpertKey) -> int:
+        """Flat row of ``key`` in the ``[L*E, ...]`` views; raises
+        ``IndexError`` unless both its layer and its expert are in range."""
+        l, e = key
+        if not (0 <= l < self.num_layers and 0 <= e < self.num_experts):
+            raise IndexError(
+                f"expert key {(l, e)} outside {self.num_layers} layers x "
+                f"{self.num_experts} experts")
+        return l * self.num_experts + e
+
     def fetch(self, keys: Sequence[ExpertKey]) -> Dict[str, np.ndarray]:
         """Batched host read: name -> [len(keys), ...] staged contiguously.
 
@@ -94,18 +108,19 @@ class HostExpertStore:
         ``ExpertCache.insert`` to dispatch the H2D transfer, which copies
         them first where ``device_put`` would alias them (the CPU).
         """
+        idx = [self._row(k) for k in keys]    # raises before any copy
         if self.chaos is not None:
             self.chaos.on_fetch(len(keys))     # may spike (sleep) or raise
         n_keys = len(keys)
         tls = self._thread_ring(n_keys)
         stage = tls.stages[tls.i]
         tls.i = (tls.i + 1) % _NUM_STAGING
-        idx = np.fromiter((l * self.num_experts + e for (l, e) in keys),
-                          np.int64, count=n_keys)
         out = {}
         for n in self.names:
-            np.take(self._flat[n], idx, axis=0, out=stage[n][:n_keys])
-            out[n] = stage[n][:n_keys]
+            src, dst = self._flat[n], stage[n]
+            for j, i in enumerate(idx):        # row copies: no temporary
+                np.copyto(dst[j], src[i])
+            out[n] = dst[:n_keys]
         if self.chaos is not None:
             self.chaos.maybe_corrupt(out)      # poisons the STAGED copy only
         return out
@@ -117,7 +132,7 @@ class HostExpertStore:
         with self._sums_lock:
             s = self._sums.get(key)
         if s is None:
-            i = key[0] * self.num_experts + key[1]
+            i = self._row(key)
             s = 0
             for n in self.names:
                 s = zlib.crc32(self._flat[n][i].tobytes(), s)

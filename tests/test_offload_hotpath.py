@@ -5,9 +5,11 @@ HostExpertStore staging/strip_experts regressions."""
 import dataclasses
 import threading
 import time
+import tracemalloc
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 import pytest
 
@@ -431,6 +433,86 @@ def test_fetch_grows_staging_for_large_batches():
     for i, (l, e) in enumerate(keys):
         np.testing.assert_array_equal(got[store.names[0]][i],
                                       store._store[store.names[0]][l, e])
+
+
+@pytest.fixture(scope="module")
+def mixtral_moe_params():
+    cfg = get_config("mixtral-8x7b").reduced(dtype="float32")
+    tparams = build_model(cfg).init(jax.random.PRNGKey(0))
+    return cfg, tparams
+
+
+def _store_as(cfg, tparams, dtype):
+    """A store over the reduced mixtral's experts cast to ``dtype``."""
+    moe = {n: np.asarray(a).astype(dtype)
+           for n, a in tparams["layers"]["moe"].items()
+           if n in ("wg", "wu", "wd")}
+    return HostExpertStore(cfg, {"layers": {"moe": moe}})
+
+
+@pytest.mark.parametrize("dtype", [np.float32, ml_dtypes.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_fetch_allocates_no_batch_sized_temporary(mixtral_moe_params, dtype):
+    """Once the thread's ring exists, a fetch copies into it and allocates
+    less than one staged row of one tensor (``np.take(out=...)`` in its
+    default mode allocates the whole batch of each tensor, then copies)."""
+    store = _store_as(*mixtral_moe_params, dtype)
+    keys = [(0, 1), (1, 2), (2, 3)]
+    store.fetch(keys)                      # warm-up: allocates the ring
+    row_bytes = min(store._store[n][0, 0].nbytes for n in store.names)
+    tracemalloc.start()
+    try:
+        got = store.fetch(keys)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < row_bytes, (peak, row_bytes)
+    for n in store.names:
+        np.testing.assert_array_equal(got[n][2], store._store[n][2, 3])
+
+
+_FETCH_CASES = {
+    "across-layers": lambda L, E: [(0, 0), (1, E - 1), (L - 1, 2)],
+    "out-of-order": lambda L, E: [(L - 1, E - 1), (0, 3), (1, 0), (0, 1)],
+    "repeated": lambda L, E: [(1, 2), (0, 5), (1, 2), (1, 2)],
+    "expert-E": lambda L, E: [(0, E)],
+    "layer-L": lambda L, E: [(L, 0)],
+    "expert-negative": lambda L, E: [(0, -1)],
+    "layer-negative": lambda L, E: [(-1, 0)],
+    "one-bad-among-good": lambda L, E: [(0, 0), (1, E), (1, 1)],
+}
+_FETCH_BAD = {"expert-E", "layer-L", "expert-negative", "layer-negative",
+              "one-bad-among-good"}
+
+
+@pytest.mark.parametrize("case", list(_FETCH_CASES))
+@pytest.mark.parametrize("dtype", [ml_dtypes.bfloat16,
+                                   ml_dtypes.float8_e4m3fn],
+                         ids=["bfloat16", "float8"])
+def test_fetch_stages_exact_rows_or_rejects_the_key(mixtral_moe_params,
+                                                   dtype, case):
+    """Staged rows are the store's bytes for every key, in the keys'
+    order, repeats included; a key whose layer or expert is out of range
+    raises before anything is staged, instead of reading another expert."""
+    store = _store_as(*mixtral_moe_params, dtype)
+    L, E = store.num_layers, store.num_experts
+    keys = _FETCH_CASES[case](L, E)
+    before = store.fetch([(0, 0)])         # the ring's other buffer
+    snap = {n: a.copy() for n, a in before.items()}
+    if case in _FETCH_BAD:
+        with pytest.raises(IndexError):
+            store.fetch(keys)
+        store.fetch([(1, 1)])              # lands in the other buffer only
+        for n in store.names:              # if the failed fetch took none
+            np.testing.assert_array_equal(before[n].view(np.uint8),
+                                          snap[n].view(np.uint8))
+        return
+    got = store.fetch(keys)
+    for n in store.names:
+        assert got[n].dtype == dtype and got[n].shape[0] == len(keys)
+        for j, (l, e) in enumerate(keys):
+            np.testing.assert_array_equal(got[n][j].view(np.uint8),
+                                          store._store[n][l, e].view(np.uint8))
 
 
 def test_strip_experts_does_not_mutate_original():
