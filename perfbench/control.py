@@ -32,23 +32,26 @@ for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from perfbench import reference, run  # noqa: E402
+from perfbench import run  # noqa: E402
+from perfbench.gaps import summary  # noqa: E402
 
 
 def readings(cell, read) -> dict:
     """The program's and the control's numbers over what a run's check read
-    (``run.run_cell``'s third result)."""
+    (``run.run_cell``'s third result), from the reference module that the
+    cell's configuration names."""
     ck = cell.checks
     per = {"gap": [], "fp8_gap": [], "best": [], "margin": []}
+    reference = read["reference"]
     for rid in read["rids"]:
         r = reference.readings(read["tparams"], cell.config["model"],
                                read["prompts"][rid][0].tolist(),
                                read["win"].tokens[rid], controls=("fp8",))
         for k in per:
             per[k].append(r[k])
-    out = {name: reference.summary(per[k], per["margin"],
-                                   float(ck["min_router_margin"]),
-                                   float(ck["wide_gap"]))
+    out = {name: summary(per[k], per["margin"],
+                         float(ck["min_router_margin"]),
+                         float(ck["wide_gap"]))
            for name, k in (("program", "gap"), ("control", "fp8_gap"))}
     out["tokens"] = {k: np.concatenate(v).tolist() for k, v in per.items()}
     return out

@@ -3,9 +3,10 @@ chip could take for every call the window made, over the device time of the
 kernel's events in the trace.
 
 Per request, from its counters: the prompt's prefill is one verify block of
-P tokens that routes to every expert of every layer (P * k >= 256 choices
-over 8 experts); each further verify block has draft_len + 1 tokens, and
-``lookups`` counts the distinct (layer, expert) pairs of every block once.
+P tokens that routes to min(E, P * k) experts of every MoE layer (the
+``first_dense_layers`` have none); each further verify block has
+draft_len + 1 tokens, and ``lookups`` counts the distinct (layer, expert)
+pairs of every block once.
 A fast block that fell back to the slow path is counted once (its useful
 work); the device time of the discarded attempt stays in the denominator.
 Bytes are the weights of the distinct experts the tokens route to plus the
@@ -32,7 +33,7 @@ def kernel_ops(ops):
 
 def least_s(run) -> float:
     cfg, pk = run.cfg, run.peaks
-    L = cfg["num_layers"]
+    L = cfg["num_layers"] - cfg.get("first_dense_layers", 0)
     E, k = cfg["num_experts"], cfg["num_experts_per_tok"]
     T = run.serving["draft_len"] + 1
     total = 0.0

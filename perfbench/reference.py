@@ -16,12 +16,14 @@ to bfloat16: the next precision below each stated one.
 
 ``cfg`` is a configuration file's ``model`` block; ``w`` is the weights
 tree (``wte``, ``head``, ``ln_f``, and ``layers`` stacked over depth with
-``ln1``, ``ln2``, ``attn.{wq,wk,wv,wo}`` and ``moe.{gate,wg,wu,wd}``).
+``ln1``, ``ln2``, ``attn.{wq,wk,wv,wo}`` and ``moe.{gate,wg,wu,wd}``).  The
+Mixtral configurations name this module as their ``"reference"``; every
+leaf is one of ``weights.make``'s own, so it declares no ``FAN_IN``.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -162,20 +164,3 @@ def readings(w, cfg: Dict[str, Any], prompt: Sequence[int],
         out[f"{q}_gap"] = best - logits[np.arange(n), clog.argmax(-1)]
     return out
 
-
-def summary(gaps: List[np.ndarray], margins: List[np.ndarray],
-            min_margin: float, wide: float) -> Dict[str, float]:
-    """Over the tokens whose routing margin is at least ``min_margin`` (a
-    smaller margin is a routing near-tie: rounding may pick either expert,
-    and the token's logits change wholesale): the mean gap, the widest, and
-    how many gaps exceed ``wide``; the widest over all tokens; how many
-    tokens there were and how many were left out."""
-    g = np.concatenate(gaps) if gaps else np.zeros((0,))
-    m = np.concatenate(margins) if margins else np.zeros((0,))
-    keep = m >= min_margin
-    kept = g[keep]
-    return {"gap_mean": float(kept.mean()) if kept.size else float("nan"),
-            "gap_max": float(kept.max()) if kept.size else float("nan"),
-            "wide_gaps": int((kept > wide).sum()),
-            "gap_max_all": float(g.max()) if g.size else float("nan"),
-            "tokens": int(g.size), "near_ties": int((~keep).sum())}

@@ -17,7 +17,8 @@ a file under ``perfbench/``.  One run:
    profiler on, its per-layer metrics (``--trace 1``), each per-layer
    metric read by ``metrics/<name>.py``;
 5. checks what the window served against the plain float32 reference
-   (reference.py), once the program's state is freed.
+   that the configuration names (``"reference"``: ``reference.py`` for
+   Mixtral), once the program's state is freed.
 
 The last line of standard output is one JSON object; the numbers compared
 and their limits are also the last lines of standard error.  Without a TPU,
@@ -32,7 +33,6 @@ PROCESS_START = time.time()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -48,6 +48,7 @@ for p in (str(ROOT / "src"), str(ROOT)):
 import numpy as np  # noqa: E402
 
 from perfbench import spec, window  # noqa: E402
+from perfbench.gaps import summary  # noqa: E402
 
 WARMUP_PASSES = 2
 
@@ -69,12 +70,8 @@ def require_accelerator(chips: int):
 
 
 def load_reader(name: str):
-    path = spec.HERE / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        "perfbench.metrics." + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return spec.load_module(spec.HERE / "metrics" / f"{name}.py",
+                            "perfbench.metrics." + name.replace(".", "_")).read
 
 
 def parse(argv=None):
@@ -147,11 +144,11 @@ def choose_checked(win: window.Window, seed: int, most: int) -> List[int]:
     return sorted([longest] + [rest[i] for i in pick])
 
 
-def check(tparams, cell, prompts, win, seed
+def check(reference, tparams, cell, prompts, win, seed
           ) -> Tuple[bool, Dict, List[str], Dict[str, Any]]:
-    """Compare the served tokens with the reference; -> (correct, checks,
-    lines for standard error, the reference's readings)."""
-    from perfbench import reference
+    """Compare the served tokens with the reference module ``reference``;
+    -> (correct, checks, lines for standard error, the reference's
+    readings)."""
     ck = cell.checks
     cfg = cell.config["model"]
     rids = choose_checked(win, seed, int(ck["max_requests"]))
@@ -161,8 +158,8 @@ def check(tparams, cell, prompts, win, seed
                                win.tokens[rid])
         gaps.append(r["gap"])
         margins.append(r["margin"])
-    res = reference.summary(gaps, margins, float(ck["min_router_margin"]),
-                            float(ck["wide_gap"]))
+    res = summary(gaps, margins, float(ck["min_router_margin"]),
+                  float(ck["wide_gap"]))
     limit = int(ck["wide_gaps"])
     kept = res["tokens"] - res["near_ties"]
     ok = kept > 0 and res["wide_gaps"] <= limit
@@ -207,11 +204,14 @@ def run_cell(args, log=print
     sizes = traffic.sizes(tr)
     vocab = cfg_t.vocab_size
 
+    reference = spec.load_reference(cell.reference)
+    fan_in = getattr(reference, "FAN_IN", {})
+
     t = time.perf_counter()
     key = weights.seed_key(args.seed)
     target, draft = build_model(cfg_t), build_model(cfg_d)
-    tparams = weights.make(target, jax.random.fold_in(key, 0))
-    dparams = weights.make(draft, jax.random.fold_in(key, 1))
+    tparams = weights.make(target, jax.random.fold_in(key, 0), fan_in)
+    dparams = weights.make(draft, jax.random.fold_in(key, 1), fan_in)
     log(f"weights: {time.perf_counter() - t:.3f} s")
     prompts = traffic.prompts(args.seed, 0, [p for p, _ in sizes], vocab)
     reqs = requests(Request, sizes, prompts)
@@ -297,8 +297,8 @@ def run_cell(args, log=print
     del engine, stream, results, dparams, draft, reqs
     gc.collect()
     t = time.perf_counter()
-    correct, checks, lines, read = check(tparams, cell, prompts, win,
-                                         args.seed)
+    correct, checks, lines, read = check(reference, tparams, cell, prompts,
+                                         win, args.seed)
     log(f"reference check: {time.perf_counter() - t:.3f} s")
     out: Dict[str, Any] = {"correct": bool(correct), "attempted": attempted,
                            "failed": failed, "metrics": metrics,
@@ -306,15 +306,16 @@ def run_cell(args, log=print
     if breakdown is not None:
         out["breakdown"] = breakdown
     out["checks"] = checks
-    return out, lines, dict(read, tparams=tparams, prompts=prompts, win=win)
+    return out, lines, dict(read, reference=reference, tparams=tparams,
+                            prompts=prompts, win=win)
 
 
 def main(argv=None) -> int:
     args = parse(argv)
     try:
-        spec.load_cell(args.workload)
+        spec.load_reference(spec.load_cell(args.workload).reference)
         import repro.core.engine  # noqa: F401  the program under test
-    except (OSError, KeyError, ImportError) as e:
+    except (OSError, KeyError, ValueError, ImportError) as e:
         print(f"perfbench: {e}", file=sys.stderr)
         return 2
     try:

@@ -3,17 +3,24 @@
 A cell (``workloads`` entry) names a configuration and a traffic mix; each is
 a JSON file found by its name (``configs/<config>.json``,
 ``traffic/<traffic>.json``), and the cell's correctness limits sit in
-``checks/<cell>.json``.  Adding a cell adds files; nothing here changes.
+``checks/<cell>.json``.  A configuration names its plain reference module
+under ``"reference"`` (``<module>.py`` beside this file): the forward pass
+the check compares against, and the rules for drawing its weights.  Adding
+a cell, or a configuration of another architecture, adds files; nothing
+here changes.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
+import re
 from pathlib import Path
 from typing import Any, Dict, List
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent                      # the checkout's root
+MODULE = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 
 
 def load_json(path: Path) -> Any:
@@ -21,15 +28,51 @@ def load_json(path: Path) -> Any:
         return json.load(f)
 
 
+def load_module(path: Path, name: str):
+    """Execute one file of the benchmark as a module of its own."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 @dataclasses.dataclass
 class Cell:
     name: str
     chips: int
     config: Dict[str, Any]
+    reference: Path                     # the configuration's reference module
     traffic: Dict[str, Any]
     checks: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]    # the metrics this cell reports
     per_layer: List[Dict[str, Any]]
+
+
+def reference_path(config: Dict[str, Any], origin: str) -> Path:
+    """The file of the reference module that ``config`` names; ``origin``
+    names the configuration's file in the error.  There is no default."""
+    name = config.get("reference")
+    if not isinstance(name, str) or not MODULE.fullmatch(name):
+        raise ValueError(f"{origin}: \"reference\" must name a module of "
+                         f"perfbench/ (a Python identifier), not {name!r}")
+    path = HERE / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{origin} names the reference module "
+                                f"{name!r}, but there is no {path}")
+    return path
+
+
+def load_reference(path: Path):
+    """The reference module in ``path``: ``forward(w, cfg, tokens, rows,
+    quant)``, ``readings(w, cfg, prompt, served, controls)`` and, optionally,
+    ``FAN_IN``, the fan-in of weight leaves that ``weights.make``'s own
+    table does not know (leaf name -> a ``ModelConfig`` field or a product
+    of fields, ``"num_heads*v_head_dim"``)."""
+    mod = load_module(path, "perfbench." + path.stem)
+    for fn in ("forward", "readings"):
+        if not callable(getattr(mod, fn, None)):
+            raise ImportError(f"reference module {path} has no {fn}()")
+    return mod
 
 
 def _applies(metric: Dict[str, Any], cell: str) -> bool:
@@ -43,9 +86,11 @@ def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json") -> Cell:
         raise KeyError(f"no workload {name!r} in {bench_path.name}; "
                        f"known: {sorted(cells)}")
     w = cells[name]
+    config_file = HERE / "configs" / f"{w['config']}.json"
+    config = load_json(config_file)
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=load_json(HERE / "configs" / f"{w['config']}.json"),
+        name=name, chips=int(w["chips"]), config=config,
+        reference=reference_path(config, str(config_file)),
         traffic=load_json(HERE / "traffic" / f"{w['traffic']}.json"),
         checks=load_json(HERE / "checks" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],

@@ -92,8 +92,12 @@ def test_the_float8_control_reads_wider_than_the_limit(on_cpu, served):
     sound program serves), so the reading does not depend on how many
     tokens the CPU served in its window."""
     import numpy as np
-    from perfbench import reference
+    from perfbench.gaps import summary
     _, _, read = served
+    # the module the run's check used is the one the configuration names
+    reference = read["reference"]
+    assert reference.__file__ == str(spec.reference_path(on_cpu.config, CELL))
+    assert on_cpu.reference.name == "reference.py"
     cfg = on_cpu.config["model"]
     gaps, cgaps, margins = [], [], []
     for p in read["prompts"][:3]:
@@ -109,8 +113,8 @@ def test_the_float8_control_reads_wider_than_the_limit(on_cpu, served):
         margins.append(r["margin"])
     ck = on_cpu.checks
     m, wide = ck["min_router_margin"], ck["wide_gap"]
-    sound = reference.summary(gaps, margins, m, wide)
-    control = reference.summary(cgaps, margins, m, wide)
+    sound = summary(gaps, margins, m, wide)
+    control = summary(cgaps, margins, m, wide)
     assert sound["gap_max"] == 0.0 and sound["wide_gaps"] == 0
     assert control["wide_gaps"] > ck["wide_gaps"]
 

@@ -4,12 +4,15 @@ The program's own ``init`` gives only the structure (``jax.eval_shape``:
 leaf names, shapes, dtypes); every value is drawn here.  Matrices are
 normal with variance 1 / fan-in, so activations and logits stay of order
 one at every width; RMSNorm gains are ones; embedding rows are unit normal.
-The reference (reference.py) reads these arrays by the same names.
+A leaf's fan-in comes first from the configuration's reference module
+(``FAN_IN``: leaf name -> a ``ModelConfig`` field or a product of fields),
+then from the table below; a leaf in neither is an error.  The reference
+reads these arrays by the same names.
 """
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +28,23 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
 
 
-def _std(name: str, shape, cfg) -> float:
+def _fan_in(rule: str, name: str, cfg) -> int:
+    """``rule``, a product of ``ModelConfig`` fields, evaluated on ``cfg``."""
+    n = 1
+    for field in rule.split("*"):
+        v = getattr(cfg, field.strip(), None)
+        if not isinstance(v, int) or isinstance(v, bool):
+            raise ValueError(f"fan-in rule {rule!r} of weight {name!r}: "
+                             f"{field.strip()!r} is no whole-number field")
+        n *= v
+    if n <= 0:
+        raise ValueError(f"fan-in rule {rule!r} of weight {name!r} gives {n}")
+    return n
+
+
+def _std(name: str, shape, cfg, fan_in: Mapping[str, str]) -> float:
+    if name in fan_in:
+        return 1.0 / math.sqrt(_fan_in(fan_in[name], name, cfg))
     if name == "wte":
         return 1.0
     if name in ("wq", "wk", "wv", "wg", "wu", "gate", "head"):
@@ -37,13 +56,15 @@ def _std(name: str, shape, cfg) -> float:
     raise ValueError(f"no init rule for weight {name!r} {tuple(shape)}")
 
 
-def make(model, key: jax.Array) -> Dict[str, Any]:
-    """Seeded weights in the structure and dtypes of ``model.init``."""
+def make(model, key: jax.Array,
+         fan_in: Optional[Mapping[str, str]] = None) -> Dict[str, Any]:
+    """Seeded weights in the structure and dtypes of ``model.init``;
+    ``fan_in``: the reference module's ``FAN_IN``."""
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     names = [path[-1].key for path, _ in leaves]
     specs = [leaf for _, leaf in leaves]
-    stds = [None if n in NORMS else _std(n, s.shape, model.cfg)
+    stds = [None if n in NORMS else _std(n, s.shape, model.cfg, fan_in or {})
             for n, s in zip(names, specs)]
 
     def init(key):
