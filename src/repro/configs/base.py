@@ -37,6 +37,11 @@ class ModelConfig:
     ffn_activation: str = "swiglu"         # swiglu | relu2 | gelu
     norm_eps: float = 1e-5
     rope_theta: float = 10000.0
+    # YaRN context extension (deepseek MLA): a factor > 1 blends interpolated
+    # and original RoPE frequencies and scales the softmax by
+    # mscale(factor, mscale_all_dim)^2; 1 = plain RoPE
+    rope_scaling_factor: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     sliding_window: Optional[int] = None   # SWA window (mixtral: 4096)
     tie_embeddings: bool = False
     attn_logit_softcap: Optional[float] = None
@@ -47,6 +52,7 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_d_ff: int = 0                      # per-expert ffn dim (0 -> d_ff)
     first_dense_layers: int = 0            # leading dense-FFN layers (deepseek)
+    norm_topk_prob: bool = True            # renormalise the top-k weights
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.001
 

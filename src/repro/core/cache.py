@@ -27,6 +27,7 @@ sequences buffer donation after pending consumers.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -69,7 +70,16 @@ class ExpertCache:
         self.lru: "OrderedDict[ExpertKey, int]" = OrderedDict()
         self.free: List[int] = list(range(num_slots))
         self.lock = threading.RLock()
-        self._insert = jax.jit(_batched_insert, donate_argnums=(0,))
+        # trace counter of the slot scatter, the regression hook of its
+        # power-of-two chunking (``insert``)
+        self.insert_traces = 0
+
+        @functools.wraps(_batched_insert)
+        def _counting_insert(bufs, stacked, slots):
+            self.insert_traces += 1            # trace-time side effect only
+            return _batched_insert(bufs, stacked, slots)
+
+        self._insert = jax.jit(_counting_insert, donate_argnums=(0,))
         # On the CPU backend jax.device_put of an aligned numpy array
         # aliases it, and the scatter ``insert`` dispatches may run after the
         # caller refills that memory (HostExpertStore.fetch reuses a staging
@@ -227,13 +237,26 @@ class ExpertCache:
                 host = {n: np.array(arr, self.dtype,
                                     copy=True if self._copy_staged else None)
                         for n, arr in picked.items()}
-                stacked = {n: jax.device_put(a) for n, a in host.items()}
                 nbytes = sum(a.nbytes for a in host.values())
                 self.h2d_bytes += nbytes
                 if stats is not None:
                     stats["h2d_bytes"] = stats.get("h2d_bytes", 0) + nbytes
-                slot_arr = jnp.array(slots, jnp.int32)
-                self.bufs = self._insert(self.bufs, stacked, slot_arr)
+                slot_arr = np.asarray(slots, np.int32)
+                # one scatter per set bit of the batch size, largest first:
+                # the jitted scatter compiles one executable per power of
+                # two instead of one per distinct batch size, and moves no
+                # padding bytes; slots go in as numpy so no eager convert
+                # compiles per length either
+                lo = 0
+                for bit in reversed(range(len(fresh).bit_length())):
+                    n = 1 << bit
+                    if not len(fresh) & n:
+                        continue
+                    stacked = {k: jax.device_put(a[lo:lo + n])
+                               for k, a in host.items()}
+                    self.bufs = self._insert(self.bufs, stacked,
+                                             slot_arr[lo:lo + n])
+                    lo += n
                 for k, s in zip(fresh, slots):
                     self.table[k] = s
                     self.lru[k] = 1 if mark_used else 0

@@ -206,6 +206,7 @@ class Request:
 # (each name is also a Metrics field)
 RUNTIME_COUNTER_KEYS = ("lookups", "hits", "on_demand_loads", "prefetched",
                         "evictions", "prefetch_evicted_unused", "h2d_bytes",
+                        "compact_bytes",
                         "host_syncs", "verify_syncs", "verify_blocks",
                         "fast_blocks", "fast_fallbacks",
                         "iterations", "drafted", "accepted",
@@ -237,6 +238,7 @@ class Metrics:
     evictions: int = 0
     prefetch_evicted_unused: int = 0
     h2d_bytes: int = 0                  # expert bytes handed to device_put
+    compact_bytes: int = 0              # expert bytes cache_moe compacts
     host_syncs: int = 0                 # every device->host sync of a turn
     verify_syncs: int = 0               # those made verifying / accepting
     verify_blocks: int = 0

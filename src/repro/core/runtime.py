@@ -66,10 +66,11 @@ intentionally not counted.
 
 Stage spans: ``jax.profiler.TraceAnnotation`` at the call sites names each
 serving stage on the profiler's host timeline, on the device ops' clock —
-``spmoe.prefill`` / ``spmoe.draft`` / ``spmoe.verify`` /
-``spmoe.verify_round`` / ``spmoe.load_wave``, all opened by the serving
-thread, so they nest.  With no profiler active a span costs its context
-manager only (its arguments are encoded only while tracing).
+``spmoe.prefill`` / ``spmoe.draft`` / ``spmoe.predict`` (inside a draft:
+one draft token's expert prediction, lookups and prefetch submissions) /
+``spmoe.verify`` / ``spmoe.verify_round`` / ``spmoe.load_wave``, all opened
+by the serving thread, so they nest.  With no profiler active a span costs
+its context manager only (its arguments are encoded only while tracing).
 
 This engine is the *internal* offload layer: construct it from an
 ``EngineConfig`` (core/engine.py) — the public request/stream API is
@@ -126,8 +127,9 @@ from repro.core.predictor import ExpertPredictor
 from repro.core.prefetcher import Prefetcher
 from repro.core import sd as S
 from repro.kernels import ops
+from repro.kernels.cache_moe import compacted_slots
 from repro.models import layers as L
-from repro.models.moe import gate_topk, ffn_forward
+from repro.models.moe import ffn_forward, gate_topk
 from repro.models.transformer import DecoderLM
 
 POLICIES = ("spmoe", "adapmoe", "moe-infinity", "on-demand")
@@ -180,7 +182,7 @@ class DecodeState:
     # between two sessions' turns (ROADMAP open item, closed).
     io: Dict[str, int] = dataclasses.field(default_factory=lambda: {
         "prefetched": 0, "evictions": 0, "prefetch_evicted_unused": 0,
-        "h2d_bytes": 0})
+        "h2d_bytes": 0, "compact_bytes": 0})
 
 
 class OffloadEngine:
@@ -265,6 +267,10 @@ class OffloadEngine:
         # launch per all-hit round regardless of how many sessions it served.
         self.verify_rounds = 0
         self.round_launches = 0
+        # expert bytes cache_moe's occupancy compaction gathers (counted at
+        # dispatch from the kernel's own rule, so nothing syncs)
+        self.compact_bytes = 0
+        self._expert_bytes = self.store.expert_bytes()
         # graceful-degradation ladder: while the prefetch plane is unhealthy
         # (worker dead beyond its restart budget, wedged past its heartbeat,
         # or circuit-breaker open on failure pressure) the offload policy
@@ -324,7 +330,8 @@ class OffloadEngine:
 
         def gate_fn(gate_w, h2):
             w, ids, probs, _ = gate_topk(gate_w, h2.reshape(-1, cfg.d_model),
-                                         cfg.num_experts_per_tok)
+                                         cfg.num_experts_per_tok,
+                                         cfg.norm_topk_prob)
             return w, ids, probs
 
         def cached_moe_apply(bufs, x, slot_ids, weights):
@@ -551,6 +558,16 @@ class OffloadEngine:
                 "moe": moe_small}
 
     # ------------------------------------------------------------- verification
+    def _count_compaction(self, st: DecodeState, rows: int, calls: int = 1):
+        """Credit ``st`` with the expert weights that ``calls`` dispatched
+        ``cache_moe`` calls over ``rows`` tokens each gather when the pool
+        is larger than the slots those tokens can occupy
+        (``kernels/cache_moe.compacted_slots``)."""
+        n = calls * compacted_slots(rows, self.cfg.num_experts_per_tok,
+                                    self.cache.num_slots) * self._expert_bytes
+        self.compact_bytes += n
+        st.io["compact_bytes"] += n
+
     def _ensure_loaded(self, layer: int, ids: np.ndarray
                        ) -> Tuple[Dict[ExpertKey, int], List[ExpertKey]]:
         keys = [(layer, int(e)) for e in dict.fromkeys(ids.ravel().tolist())]
@@ -632,6 +649,8 @@ class OffloadEngine:
                 logits, ok, ncache, nhist, nact = self._verify_fast(
                     self._rp, bufs, table, st.history_dev, tokens, pos,
                     tcache)
+            self._count_compaction(st, tokens.shape[1],
+                                   self.store.num_layers)
             if bool(self._readback(ok, verify=True)):   # 1 of ≤2 a block
                 st.history_dev = nhist
                 st.fast_active_dev = st.fast_active_dev + nact
@@ -690,6 +709,7 @@ class OffloadEngine:
                 bufs, _ = self.cache.snapshot()
                 y = self._moe_apply(bufs, xf,
                                     jnp.asarray(slot_lut[ids_np], jnp.int32), w)
+            self._count_compaction(st, T)
             total_misses += len(misses)
             if misses:
                 # on-demand batched loads, in cache-capacity-bounded waves:
@@ -712,6 +732,7 @@ class OffloadEngine:
                         y = y + self._moe_apply(
                             bufs, xf,
                             jnp.asarray(wave_lut[ids_np], jnp.int32), w)
+                    self._count_compaction(st, T)
             x = self._shared_res(lp, x, h2, y.reshape(1, T, cfg.d_model))
         tcache["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *new_layers)
         if self.policy != "adapmoe":
@@ -812,20 +833,27 @@ class OffloadEngine:
                 tok = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None]
                 drafts.append(int(self._readback(tok)[0, 0]))
                 if self.policy == "spmoe" and self.cutoff >= 0:
-                    tap_stack = self._draft_taps_for_moe(taps)
-                    for l in range(min(self.cutoff + 1,
-                                       self.store.num_layers)):
-                        keys = self.predictor.predict_layer(
-                            l, tap_stack[l], self._readback)
-                        # see moe-infinity note: predictions substitute for
-                        # LRU touches while the fast path is armed
-                        _, miss = self.cache.lookup(keys, touch=st.fast_ok)
-                        if miss:
-                            self._prefetch(st, miss)
+                    with jax.profiler.TraceAnnotation(
+                            "spmoe.predict", request_id=st.request_id):
+                        self._predict_and_prefetch(st, taps)
         block = jnp.concatenate(
             [st.cur, jnp.asarray([drafts], jnp.int32)], axis=1) \
             if drafts else st.cur
         return drafts, block
+
+    def _predict_and_prefetch(self, st: DecodeState, taps):
+        """SP-MoE's prediction for one draft token: each target MoE layer
+        up to the cutoff scores the draft's tap through its own router,
+        looks the predicted experts up, and submits the misses."""
+        tap_stack = self._draft_taps_for_moe(taps)
+        for l in range(min(self.cutoff + 1, self.store.num_layers)):
+            keys = self.predictor.predict_layer(l, tap_stack[l],
+                                                self._readback)
+            # see moe-infinity note: predictions substitute for LRU touches
+            # while the fast path is armed
+            _, miss = self.cache.lookup(keys, touch=st.fast_ok)
+            if miss:
+                self._prefetch(st, miss)
 
     def _turn_commit(self, st: DecodeState, drafts: List[int],
                      greedy: np.ndarray) -> List[int]:
@@ -998,6 +1026,9 @@ class OffloadEngine:
                         tuple(blocks),
                         tuple(st.pos for st in sts),
                         tuple(st.tcache for st in sts))
+            # the round's one compaction per layer is charged to its first
+            # session, as its two syncs are
+            self._count_compaction(sts[0], offs[-1], self.store.num_layers)
             ok = self._readback(ok_vec, verify=True)        # round sync 1
             greedy = self._readback(jnp.argmax(logits, -1),
                                     verify=True)[0]         # round sync 2
@@ -1141,6 +1172,7 @@ class OffloadEngine:
             "evictions": self.cache.evictions,
             "prefetch_evicted_unused": self.cache.prefetch_evicted,
             "h2d_bytes": self.cache.h2d_bytes,
+            "compact_bytes": self.compact_bytes,
             "host_syncs": self.host_syncs,
             "verify_syncs": self.verify_syncs,
             "verify_blocks": self.verify_blocks,
@@ -1180,6 +1212,7 @@ class OffloadEngine:
         self.iterations = self.drafted = self.accepted = 0
         self.verify_rounds = self.round_launches = 0
         self.degraded_rounds = self.io_errors = 0
+        self.compact_bytes = 0
         self.store.checksum_failures = 0
         self.cache.reset_stats()
         self.prefetcher.reset_stats()

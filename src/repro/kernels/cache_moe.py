@@ -15,13 +15,17 @@ misses, or entries masked out of a compute wave) contribute exactly zero —
 cached-first and miss-wave compute share this one fused path, differing only
 in which slots are masked.
 
-Verification blocks are tiny (N+1 tokens × k choices), so the capacity per
-slot is the worst case ``T·k`` rounded up to the block size — no drops, which
-speculative-decoding losslessness requires.  A block can route to at most
-``T·k`` *distinct* slots, so when the pool is larger than that the slot axis
-is **occupancy-compacted** before the GEMM: the ≤ ``min(S, T·k)`` slots that
-actually received a choice are renumbered densely, only their weight rows are
-gathered, and the grid covers ``M = min(S, T·k)`` slots instead of ``S`` —
+A slot receives at most one choice per token: a token's k experts are
+distinct (``lax.top_k``) and the page table maps distinct experts to
+distinct slots.  So the capacity per slot is ``T`` rows, rounded up to the
+row block and never under 16 — no drops, which speculative-decoding
+losslessness requires (``T·k`` rows would be six times as many at top-6).
+Callers keep to that: a token names a slot at most once.  A block can
+route to at most ``T·k`` *distinct* slots, so when the pool is larger than
+that the slot axis is **occupancy-compacted** before the GEMM: the ≤
+``min(S, T·k)`` slots that actually received a choice are renumbered
+densely, only their weight rows are gathered (``compacted_slots``), and the
+grid covers ``M = min(S, T·k)`` slots instead of ``S`` —
 FLOPs and weight traffic are O(M·C·d·f), independent of the pool size.
 (Previously the grid covered all S slots at capacity C, burning O(S·C·d·f)
 on empty slots — ROADMAP open item, closed.)  Each row's blocked
@@ -39,16 +43,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.moe_gemm import _down_kernel, _gate_up_kernel, moe_gemm
+from repro.kernels.moe_gemm import (_down_kernel, _gate_up_kernel, block_f_for,
+                                    moe_gemm)
+
+MIN_CAPACITY = 16
 
 
-def _capacity(n_choices: int, block_c: int) -> int:
-    """Smallest valid per-slot capacity: >= n_choices (zero drops), rounded so
-    the blocked kernel's ``C % bc == 0`` constraint holds."""
-    c = max(8, -(-n_choices // 8) * 8)
+def _capacity(rows: int, block_c: int) -> int:
+    """Smallest valid per-slot capacity: >= ``rows``, the most choices one
+    slot can receive (zero drops), and >= ``MIN_CAPACITY``, rounded so the
+    blocked kernel's ``C % bc == 0`` constraint holds."""
+    c = max(MIN_CAPACITY, -(-rows // 8) * 8)
     if c > block_c:
         c = -(-c // block_c) * block_c
     return c
+
+
+def compacted_slots(tokens: int, k: int, num_slots: int) -> int:
+    """How many slots ``cache_moe`` compacts a pool of ``num_slots`` to for
+    ``tokens`` rows of k choices: M = min(S, T·k) when the pool is larger
+    (its weight rows are gathered before the grid), else 0 (no gather)."""
+    m = min(num_slots, tokens * k)
+    return m if num_slots > m else 0
 
 
 def compact_occupied_slots(slot_ids: jax.Array, wu: jax.Array, wd: jax.Array,
@@ -132,11 +148,11 @@ def _up_gelu_kernel(x_ref, wu_ref, h_ref, acc_ref):
 
 
 def _gelu_grouped(xg: jax.Array, wu: jax.Array, wd: jax.Array,
-                  valid: jax.Array, *, block_c: int, block_f: int,
+                  valid: jax.Array, *, block_c: int, block_f: Optional[int],
                   block_d: int, interpret: bool) -> jax.Array:
     S, C, d = xg.shape
     f = wu.shape[2]
-    bc, bf, bd = min(block_c, C), min(block_f, f), min(block_d, d)
+    bc, bf, bd = min(block_c, C), block_f_for(f, block_f), min(block_d, d)
     assert C % bc == 0 and f % bf == 0 and d % bd == 0
     h = pl.pallas_call(
         _up_gelu_kernel,
@@ -171,16 +187,17 @@ def _gelu_grouped(xg: jax.Array, wu: jax.Array, wd: jax.Array,
 
 def cache_moe(x: jax.Array, slot_ids: jax.Array, weights: jax.Array,
               wu: jax.Array, wd: jax.Array, wg: Optional[jax.Array] = None,
-              *, block_c: int = 128, block_f: int = 512, block_d: int = 512,
-              interpret: bool = False) -> jax.Array:
+              *, block_c: int = 128, block_f: Optional[int] = None,
+              block_d: int = 512, interpret: bool = False) -> jax.Array:
     """x: [T, d]; slot_ids/weights: [T, k]; wu/wg: [S, d, f]; wd: [S, f, d]
-    -> y [T, d].  slot_ids < 0 (miss / masked-out wave) contribute zero."""
+    -> y [T, d].  slot_ids < 0 (miss / masked-out wave) contribute zero; a
+    token names each slot at most once."""
     T, d = x.shape
     k = slot_ids.shape[1]
     S = wu.shape[0]
-    C = _capacity(T * k, block_c)
-    M = min(S, T * k)
-    if S > M:          # occupancy compaction: grid covers M slots, not S
+    C = _capacity(T, block_c)
+    M = compacted_slots(T, k, S)
+    if M:              # occupancy compaction: grid covers M slots, not S
         slot_ids, wu, wd, wg = compact_occupied_slots(slot_ids, wu, wd, wg, M)
         S = M
     idx, valid, pos = dispatch_to_slots(slot_ids, S, C)

@@ -16,6 +16,7 @@ Oracle: kernels/ref.moe_gemm_ref.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,14 +59,38 @@ def _down_kernel(h_ref, wd_ref, y_ref, acc_ref):
         y_ref[0] = acc_ref[...].astype(y_ref.dtype)
 
 
+# An expert at most this wide is one block along f: at block_c 128 and
+# block_d 512 its double-buffered gate/up tiles and f32 accumulators take
+# about 8 MB of VMEM (DeepSeek-V2-Lite's 1408 = 11 x 128, whose only
+# lane-aligned blocks are 128 and 1408).  Wider experts take the largest
+# lane-aligned block up to BLOCK_F that divides them.
+FULL_F = 2048
+BLOCK_F = 512
+
+
+def block_f_for(f: int, block_f: Optional[int] = None) -> int:
+    """The f block: ``block_f`` when given (it must divide f), else f whole
+    up to ``FULL_F``, else the largest multiple of 128 up to ``BLOCK_F``
+    that divides f."""
+    if block_f is None:
+        block_f = f if f <= FULL_F else max(
+            (b for b in range(128, BLOCK_F + 1, 128) if f % b == 0),
+            default=BLOCK_F)
+    bf = min(block_f, f)
+    if f % bf:
+        raise ValueError(f"block_f {bf} does not divide the expert width {f}")
+    return bf
+
+
 def moe_gemm(xg: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
-             valid: jax.Array, *, block_c: int = 128, block_f: int = 512,
-             block_d: int = 512, interpret: bool = False) -> jax.Array:
+             valid: jax.Array, *, block_c: int = 128,
+             block_f: Optional[int] = None, block_d: int = 512,
+             interpret: bool = False) -> jax.Array:
     """xg: [E,C,d]; wg/wu: [E,d,f]; wd: [E,f,d]; valid: [E,C] -> [E,C,d]."""
     E, C, d = xg.shape
     f = wg.shape[2]
     bc = min(block_c, C)
-    bf = min(block_f, f)
+    bf = block_f_for(f, block_f)
     bd = min(block_d, d)
     assert C % bc == 0 and f % bf == 0 and d % bd == 0
 

@@ -8,6 +8,7 @@ Compute dtype follows the input; reductions (softmax, norms) run in f32.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -29,11 +30,15 @@ def _dense_init(key, shape, dtype, scale: float = 1.0):
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    dt = x.dtype
+def rms_normalize(x: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm without a gain, computed in f32, returned in x's dtype."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(var + eps)).astype(dt) * weight
+    return (xf * jax.lax.rsqrt(var + eps)).astype(x.dtype)
+
+
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    return rms_normalize(x, eps) * weight
 
 
 def init_rms_norm(d: int, dtype) -> jax.Array:
@@ -49,10 +54,13 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., S, H, D]; positions: broadcastable to [..., S] (int32)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv: Optional[jax.Array] = None) -> jax.Array:
+    """x: [..., S, H, D]; positions: broadcastable to [..., S] (int32).
+    ``inv`` overrides the inverse frequencies (YaRN, :func:`mla_rope_freqs`)."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                                  # [D/2]
+    if inv is None:
+        inv = rope_freqs(d, theta)                              # [D/2]
     ang = positions.astype(jnp.float32)[..., None] * inv        # [..., S, D/2]
     cos = jnp.cos(ang)[..., None, :]                            # [..., S, 1, D/2]
     sin = jnp.sin(ang)[..., None, :]
@@ -66,18 +74,20 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def mha(q: jax.Array, k: jax.Array, v: jax.Array,
-        mask: Optional[jax.Array], softcap: Optional[float] = None) -> jax.Array:
+        mask: Optional[jax.Array], softcap: Optional[float] = None,
+        scale: Optional[float] = None) -> jax.Array:
     """q: [B,Sq,H,D]  k: [B,Skv,Hkv,D]  v: [B,Skv,Hkv,Dv]  -> [B,Sq,H,Dv].
 
     GQA via head-group reshape; mask broadcastable to [B, 1|Hkv, 1|rep, Sq, Skv]
-    (True = attend).  Softmax in f32.
+    (True = attend).  Softmax in f32, over scores scaled by ``scale``
+    (default 1/sqrt(D)).
     """
     B, Sq, H, D = q.shape
     Hkv = k.shape[2]
     rep = H // Hkv
     qg = q.reshape(B, Sq, Hkv, rep, D)
     scores = jnp.einsum("bqkrd,bskd->bkrqs", qg, k).astype(jnp.float32)
-    scores = scores / np.sqrt(D)
+    scores = scores / np.sqrt(D) if scale is None else scores * scale
     if softcap is not None:
         scores = jnp.tanh(scores / softcap) * softcap
     if mask is not None:
@@ -209,6 +219,73 @@ def attention_decode(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
 # ---------------------------------------------------------------------------
 # MLA attention (deepseek-v2): compressed KV cache + absorbed decode
 # ---------------------------------------------------------------------------
+#
+# As published (DeepSeek-V2, arXiv:2405.04434; ``DeepseekV2Attention`` of
+# its modeling_deepseek.py, q_lora_rank null): q = x·wq split into nope and
+# rope parts; the latent c_kv = RMSNorm(x·wdkv) (``kv_a_layernorm``) and the
+# shared rotary key k_pe = x·wkr; k_nope = c_kv·wuk, v = c_kv·wuv
+# (``kv_b_proj``); RoPE with YaRN on q_pe and k_pe; softmax at
+# (nope + rope)^-1/2 · mscale².  Departure: the latent norm's gain is not a
+# parameter of its own.  It is a per-channel scale right before wuk/wuv, so
+# it is folded into them, which is exact; the norm here has no gain.  HF's
+# interleaved rope layout is a fixed permutation of wq's and wkr's rope
+# columns: a relabelling of the weights, not a departure.
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """``yarn_get_mscale`` of DeepSeek-V2's modeling_deepseek.py."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+# YaRN's correction range and original context, as DeepSeek-V2-Lite's
+# published config.json states them (``rope_scaling``).  Its ``mscale``
+# equals ``mscale_all_dim``, so the cos/sin factor mscale(factor, mscale) /
+# mscale(factor, mscale_all_dim) of ``DeepseekV2YarnRotaryEmbedding`` is 1.
+YARN_ORIGINAL_MAX_POSITION = 4096
+YARN_BETA_FAST = 32
+YARN_BETA_SLOW = 1
+
+
+def mla_rope_freqs(cfg: ModelConfig) -> jax.Array:
+    """Inverse frequencies [rope_head_dim // 2] of MLA's rotary part.
+    With ``rope_scaling_factor`` > 1, YaRN as
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: below the correction
+    range (``YARN_BETA_FAST`` rotations over the original context) the
+    original frequencies, above it (``YARN_BETA_SLOW``) the interpolated
+    ones (÷ factor), a linear ramp between.  It acts at every position."""
+    dim, base = cfg.rope_head_dim, cfg.rope_theta
+    extra = rope_freqs(dim, base)
+    factor = cfg.rope_scaling_factor
+    if factor <= 1:
+        return extra
+
+    def correction_dim(rotations):
+        return dim * math.log(YARN_ORIGINAL_MAX_POSITION
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(correction_dim(YARN_BETA_SLOW)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-1/2, times mscale(factor, mscale_all_dim)² with YaRN."""
+    scale = (cfg.head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.rope_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_scaling_factor,
+                             cfg.rope_mscale_all_dim) ** 2
+    return scale
+
+
+def mla_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig
+             ) -> jax.Array:
+    return apply_rope(x, positions, cfg.rope_theta, inv=mla_rope_freqs(cfg))
+
 
 def init_mla(key, cfg: ModelConfig, dtype) -> Params:
     d, H = cfg.d_model, cfg.num_heads
@@ -224,6 +301,17 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Params:
     }
 
 
+def mla_latent(p: Params, x: jax.Array, positions: jax.Array,
+               cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """What MLA caches for x [B,S,d] at ``positions`` (broadcastable to
+    [B,S]): the normalised latent c_kv [B,S,r] and the rotated shared key
+    k_rope [B,S,rope]."""
+    c_kv = rms_normalize(jnp.einsum("bsd,dr->bsr", x, p["wdkv"]), cfg.norm_eps)
+    k_rope = mla_rope(jnp.einsum("bsd,dk->bsk", x, p["wkr"])[:, :, None, :],
+                      positions, cfg)[:, :, 0, :]
+    return c_kv, k_rope
+
+
 def mla_forward(p: Params, x: jax.Array, cfg: ModelConfig,
                 positions: Optional[jax.Array] = None) -> jax.Array:
     """Full-sequence MLA (train / prefill): expand the latent, run GQA-style."""
@@ -233,16 +321,15 @@ def mla_forward(p: Params, x: jax.Array, cfg: ModelConfig,
         positions = jnp.arange(S)[None, :]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    c_kv = jnp.einsum("bsd,dr->bsr", x, p["wdkv"])                 # latent
-    k_rope = apply_rope(jnp.einsum("bsd,dk->bsk", x, p["wkr"])[:, :, None, :],
-                        positions, cfg.rope_theta)                 # [B,S,1,rd]
+    q_rope = mla_rope(q_rope, positions, cfg)
+    c_kv, k_rope = mla_latent(p, x, positions, cfg)
     k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["wuk"])
     v = jnp.einsum("bsr,rhk->bshk", c_kv, p["wuv"])
     H = cfg.num_heads
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, cfg.rope_head_dim))], -1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope[:, :, None, :], (B, S, H, cfg.rope_head_dim))], -1)
     qf = jnp.concatenate([q_nope, q_rope], -1)
-    out = mha(qf, k, v, causal_mask(S, S))
+    out = mha(qf, k, v, causal_mask(S, S), scale=mla_softmax_scale(cfg))
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 
@@ -258,27 +345,24 @@ def mla_decode(p: Params, x: jax.Array, cache: Dict[str, jax.Array],
                pos: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Absorbed-matmul MLA decode: attention runs in the `kv_lora` latent
     space, so per-step cost is O(S·r) instead of O(S·H·head_dim) and the cache
-    stays compressed.  Scaling uses the expanded head dim (nope+rope) to match
-    the full-sequence path exactly.  Handles Sq >= 1 (verification blocks).
+    stays compressed.  Scaling is the full-sequence path's
+    (:func:`mla_softmax_scale`).  Handles Sq >= 1 (verification blocks).
     """
     B, Sq, _ = x.shape
-    nope, rd, r = cfg.head_dim, cfg.rope_head_dim, cfg.kv_lora_rank
-    S = cache["c_kv"].shape[1]
+    nope = cfg.head_dim
     qpos = pos + jnp.arange(Sq, dtype=jnp.int32)
     pp = jnp.broadcast_to(qpos[None, :], (B, Sq))
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])                    # [B,Sq,H,nope+rd]
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, pp, cfg.rope_theta)
+    q_rope = mla_rope(q_rope, pp, cfg)
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, p["wuk"])         # absorb W_uk
-    c_new = jnp.einsum("bsd,dr->bsr", x, p["wdkv"])
-    kr_new = apply_rope(jnp.einsum("bsd,dk->bsk", x, p["wkr"])[:, :, None, :],
-                        pp, cfg.rope_theta)[:, :, 0, :]
+    c_new, kr_new = mla_latent(p, x, pp, cfg)
     c_kv = jax.lax.dynamic_update_slice(cache["c_kv"], c_new, (0, pos, 0))
     k_rope = jax.lax.dynamic_update_slice(cache["k_rope"], kr_new, (0, pos, 0))
     pos_map = jax.lax.dynamic_update_slice(cache["pos_map"], qpos, (pos,))
     scores = (jnp.einsum("bshr,btr->bhst", q_lat, c_kv) +
               jnp.einsum("bshk,btk->bhst", q_rope, k_rope)).astype(jnp.float32)
-    scores = scores / np.sqrt(nope + rd)
+    scores = scores * mla_softmax_scale(cfg)
     valid = (pos_map[None, :] <= qpos[:, None]) & (pos_map[None, :] >= 0)
     scores = jnp.where(valid[None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)

@@ -43,17 +43,22 @@ def init_moe(key, cfg: ModelConfig, dtype) -> Params:
     return p
 
 
-def gate_topk(gate_w: jax.Array, x: jax.Array, k: int
+def gate_topk(gate_w: jax.Array, x: jax.Array, k: int,
+              norm_topk_prob: bool = True
               ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """x: [..., d] -> (weights [..., k], ids [..., k], probs [..., E], aux).
 
-    Mixtral-style: softmax over all experts, take top-k, renormalize.
+    Softmax over all experts, then the top-k.  ``norm_topk_prob`` (Mixtral)
+    renormalises the k weights to sum to one; without it (DeepSeek-V2) they
+    stay the raw softmax probabilities.  Callers pass the config's
+    ``norm_topk_prob``.
     aux = switch load-balancing loss (E · mean(frac_routed · mean_prob)).
     """
     logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), gate_w)
     probs = jax.nn.softmax(logits, axis=-1)
     weights, ids = jax.lax.top_k(probs, k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     E = gate_w.shape[-1]
     flat_ids = ids.reshape(-1, k)
     counts = jnp.sum(jax.nn.one_hot(flat_ids, E, dtype=jnp.float32), axis=(0, 1))
@@ -104,7 +109,7 @@ def moe_grouped(p: Params, x: jax.Array, cfg: ModelConfig
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     C = int(np.ceil(cfg.capacity_factor * S * k / E))
     C = max(8, min(S, -(-C // 8) * 8))  # round up to 8, cap at S
-    weights, ids, _, aux = gate_topk(p["gate"], x, k)
+    weights, ids, _, aux = gate_topk(p["gate"], x, k, cfg.norm_topk_prob)
 
     idx, valid, slot = jax.vmap(lambda i: _dispatch_indices(i, E, C))(ids)
     #   idx/valid: [B, E, C]; slot: [B, S, k]
@@ -144,7 +149,7 @@ def moe_global(p: Params, x: jax.Array, cfg: ModelConfig
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     T = B * S
     xf = x.reshape(T, d)
-    weights, ids, _, aux = gate_topk(p["gate"], xf, k)
+    weights, ids, _, aux = gate_topk(p["gate"], xf, k, cfg.norm_topk_prob)
     flat = ids.reshape(-1)                                  # [T*k]
     order = jnp.argsort(flat, stable=True)
     xs = xf[order // k]                                     # [T*k, d] sorted by expert
@@ -175,7 +180,7 @@ def moe_ref(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     Used by tests to validate the routed paths."""
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
-    weights, ids, _, _ = gate_topk(p["gate"], x, k)
+    weights, ids, _, _ = gate_topk(p["gate"], x, k, cfg.norm_topk_prob)
     xf = x.reshape(-1, d)
     wf = weights.reshape(-1, k)
     idf = ids.reshape(-1, k)

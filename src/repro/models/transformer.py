@@ -109,9 +109,7 @@ def _attn_prefill_cache(p, h, cfg: ModelConfig, cache, positions):
     if positions is None:
         positions = jnp.arange(S)[None, :]
     if cfg.use_mla:
-        c_kv = jnp.einsum("bsd,dr->bsr", h, p["attn"]["wdkv"])
-        k_rope = L.apply_rope(jnp.einsum("bsd,dk->bsk", h, p["attn"]["wkr"])[:, :, None, :],
-                              positions, cfg.rope_theta)[:, :, 0, :]
+        c_kv, k_rope = L.mla_latent(p["attn"], h, positions, cfg)
         cache = dict(cache)
         cache["c_kv"] = jax.lax.dynamic_update_slice(cache["c_kv"], c_kv, (0, 0, 0))
         cache["k_rope"] = jax.lax.dynamic_update_slice(cache["k_rope"], k_rope, (0, 0, 0))

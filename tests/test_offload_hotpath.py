@@ -191,6 +191,31 @@ def test_table_scatter_trace_count_bounded():
         f"scatter retraced per length: {cache.table_scatter_traces} traces"
 
 
+def test_slot_scatter_compiles_one_executable_per_power_of_two():
+    """A fine-grained model's load waves and prefetch batches take many
+    sizes (top-6 of 64 experts: 1 to 30 fresh keys a layer), and a scatter
+    compiled per size compiled inside the serving window.  The insert now
+    scatters one power-of-two chunk per set bit of the batch size: 30
+    distinct sizes compile at most 5 executables, and every slot holds the
+    row inserted for it."""
+    cache = ExpertCache(32, {"w": (3,)}, jnp.float32, table_shape=(1, 64))
+    for n in range(1, 31):
+        cache.free = list(range(32))          # start each batch empty
+        cache.table.clear()
+        cache.lru.clear()
+        cache.table_dev = jnp.full((1, 64), -1, jnp.int32)
+        keys = [(0, e) for e in range(n)]
+        rows = np.stack([np.full((3,), 100 * n + i, np.float32)
+                         for i in range(n)])
+        slots = cache.insert(keys, {"w": rows})
+        bufs = np.asarray(cache.bufs["w"])
+        for i, s in enumerate(slots):
+            np.testing.assert_array_equal(bufs[s], rows[i])
+        assert cache.check_invariants()
+    assert cache.insert_traces == 5, \
+        f"slot scatter compiled per size: {cache.insert_traces} traces"
+
+
 def test_table_array_consistent_under_concurrent_prefetch():
     """Prefetch worker + compute loop hammer the cache concurrently; the
     invariants (incl. the device table mirror) must hold throughout."""

@@ -1,8 +1,8 @@
 """The serving loop's profiler spans and its sync and transfer counters.
 
 Spans: serving under ``jax.profiler`` names every stage (prefill, draft,
-verify, fused round, load wave), all on the serving thread, and load waves
-nest in a verify or a prefill.  Syncs: every device->host conversion
+prediction, verify, fused round, load wave), all on the serving thread;
+load waves nest in a verify or a prefill, predictions in a draft.  Syncs: every device->host conversion
 of a jax array the serving thread makes during a turn is counted in
 ``host_syncs``.  Transfers: ``h2d_bytes`` per request tiles the engine's
 cumulative counter, and a resident key transfers nothing."""
@@ -20,7 +20,7 @@ from repro.core.engine import Engine, EngineConfig, Request
 from repro.models.registry import build_model
 
 TOK = 10
-STAGES = ("spmoe.prefill", "spmoe.draft", "spmoe.verify",
+STAGES = ("spmoe.prefill", "spmoe.draft", "spmoe.predict", "spmoe.verify",
           "spmoe.verify_round", "spmoe.load_wave")
 
 
@@ -106,6 +106,8 @@ def test_serving_spans_name_every_stage_and_nest(ms, tmp_path):
                if o[0] == "spmoe.prefill")
     # drafting and verifying never overlap: one serving thread, in turn
     drafts = [e for e in evs if e[0] == "spmoe.draft"]
+    predicts = [e for e in evs if e[0] == "spmoe.predict"]
+    assert all(any(_inside(p, d) for d in drafts) for p in predicts)
     assert not any(_inside(d, o) or _inside(o, d)
                    for d in drafts for o in outer)
 
@@ -226,3 +228,37 @@ def test_h2d_bytes_tile_and_a_resident_key_transfers_nothing(ms):
         cache.insert([fresh], rt.store.fetch([fresh]), stats=stats)
         assert cache.h2d_bytes - h0 == stats["h2d_bytes"] == \
             rt.store.expert_bytes()
+
+
+# ---------------------------------------------------------------------------
+# compact_bytes
+# ---------------------------------------------------------------------------
+
+def test_compact_bytes_count_the_kernels_gather_per_request(ms):
+    """A pool no larger than a block's T*k choices gathers nothing; a larger
+    one gathers M = T*k experts' weights in every cache_moe call, which the
+    requests' counters tile, and one fast decode block is one call per MoE
+    layer."""
+    cfg = ms[0]
+    with _engine(ms, 6) as eng:                 # 6 <= T*k = 4 * 2
+        res = eng.serve_all(_reqs(ms[4]), concurrency=1)
+        assert eng.runtime.counters()["compact_bytes"] == 0
+        assert all(r.metrics.compact_bytes == 0 for r in res)
+    with _engine(ms, _ample(ms)) as eng:        # 16 slots > 8
+        rt = eng.runtime
+        res = eng.serve_all(_reqs(ms[4]), concurrency=1)
+        total = rt.counters()["compact_bytes"]
+        per_call = 4 * cfg.num_experts_per_tok * rt.store.expert_bytes()
+        assert total > 0 and total % per_call == 0
+        assert sum(r.metrics.compact_bytes for r in res) == total
+        assert eng.metrics().compact_bytes == total
+        # one armed block on the warm cache, every expert resident
+        st = rt.start_session(ms[4][0], TOK)
+        rt.session_turn(st)                     # delivers the prefill token
+        st.fast_ok = True
+        c0, i0, f0 = rt.compact_bytes, st.io["compact_bytes"], rt.fast_blocks
+        rt.session_turn(st)
+        rt.finish_session(st)
+        assert rt.fast_blocks == f0 + 1
+        assert rt.compact_bytes - c0 == st.io["compact_bytes"] - i0 == \
+            cfg.num_moe_layers * per_call
